@@ -25,6 +25,9 @@ def main() -> None:
     )
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     from benchmarks import (
         block_microbench,
         common,

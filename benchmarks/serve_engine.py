@@ -92,6 +92,7 @@ import sys
 import time
 from pathlib import Path
 
+import jax
 import numpy as np
 
 from benchmarks.common import emit, emit_json
@@ -1116,10 +1117,22 @@ def run(
     good = _measure_goodput(cfg, mesh, server.params, batch, smoke)
 
     # ---- mesh: TP decode scaling + DP replica routing on a simulated
-    # 8-device host mesh (subprocess — see _measure_mesh)
-    meshrow = _measure_mesh(smoke)
+    # 8-device host mesh (subprocess — see _measure_mesh). On a TPU host
+    # this process holds the chip, and a CPU child's numbers would sit
+    # beside chip numbers in one file: the block is left unmeasured.
+    dev = jax.devices()[0]
+    if dev.platform == "tpu":
+        meshrow = {
+            "status": "not measured",
+            "reason": "simulated-CPU mesh child skipped on a TPU host",
+        }
+    else:
+        meshrow = _measure_mesh(smoke)
 
     payload = {
+        "platform": dev.platform,
+        "device_kind": dev.device_kind,
+        "device_count": len(jax.devices()),
         "config": {
             "arch": ARCH,
             "smoke": True,

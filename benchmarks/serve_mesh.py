@@ -49,9 +49,9 @@ import time  # noqa: E402
 
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
-from jax.sharding import Mesh  # noqa: E402
 
 from repro.configs import registry  # noqa: E402
+from repro.launch.mesh import make_tp_mesh  # noqa: E402
 from repro.serving import Engine, EngineConfig, SamplingParams  # noqa: E402
 from repro.serving.router import ReplicaRouter  # noqa: E402
 
@@ -60,11 +60,6 @@ ARCH = "qwen3-1.7b"
 
 def _log(msg: str) -> None:
     print(f"serve_mesh: {msg}", file=sys.stderr, flush=True)
-
-
-def _sub_mesh(tp: int) -> Mesh:
-    sub = np.asarray(jax.devices()[:tp]).reshape(1, tp)
-    return Mesh(sub, ("data", "model"))
 
 
 def _serve(eng, prompts, gen: int, sampled: bool) -> dict[int, list[int]]:
@@ -88,7 +83,7 @@ def _measure_tp(tp: int, cfg, prompts, gen: int, repeats: int):
     repeats and keep the best decode tok/s."""
     eng = Engine(
         cfg,
-        _sub_mesh(tp),
+        make_tp_mesh(tp),
         engine_cfg=EngineConfig(
             max_slots=len(prompts), max_len=len(prompts[0]) + gen + 1
         ),

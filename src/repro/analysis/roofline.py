@@ -14,10 +14,9 @@ and accounts:
     assignment's definition.
 
 All quantities are PER DEVICE because the HLO is the per-device SPMD
-program; roofline terms therefore divide by per-chip peak rates.
-
-Hardware constants: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link
-ICI (3D-torus; one link's worth as the serial bottleneck model).
+program; roofline terms therefore divide by per-chip peak rates, looked
+up in ``PEAKS`` by the device's ``device_kind``. A device missing from
+the table has no roofline: ``peaks_for`` raises instead of guessing.
 """
 
 from __future__ import annotations
@@ -29,7 +28,9 @@ from typing import Iterable
 
 __all__ = [
     "HW",
+    "PEAKS",
     "HloCost",
+    "peaks_for",
     "analyze_hlo",
     "roofline_terms",
     "model_flops",
@@ -38,9 +39,30 @@ __all__ = [
 
 @dataclasses.dataclass(frozen=True)
 class HW:
-    peak_flops: float = 197e12  # bf16 per chip
-    hbm_bw: float = 819e9  # bytes/s per chip
-    ici_bw: float = 50e9  # bytes/s per link
+    peak_flops: float  # bf16 FLOP/s per chip
+    hbm_bw: float  # HBM bytes/s per chip
+    ici_bw: float  # bytes/s per ICI link (serial bottleneck model)
+
+
+# Per-chip peaks keyed by ``jax.Device.device_kind``.
+# "TPU v5 lite" is the TPU v5e. Source: Google Cloud documentation, "TPU
+# v5e": 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s ICI over 4 links
+# (50 GB/s per link).
+PEAKS: dict[str, HW] = {
+    "TPU v5 lite": HW(peak_flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+
+
+def peaks_for(device_kind: str) -> HW:
+    """The peak table's entry for ``device_kind``; KeyError when the
+    device is not in ``PEAKS`` (no roofline is better than a wrong one)."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no peak rates for device_kind {device_kind!r}; "
+            f"known: {sorted(PEAKS)}"
+        ) from None
 
 
 _DTYPE_BYTES = {
@@ -253,7 +275,7 @@ def analyze_hlo(text: str) -> HloCost:
 # ----------------------------------------------------------------------
 
 
-def roofline_terms(cost: HloCost, hw: HW = HW()) -> dict:
+def roofline_terms(cost: HloCost, hw: HW) -> dict:
     """Per-device time lower bounds for the three roofline terms."""
     t_c = cost.flops / hw.peak_flops
     t_m = cost.bytes_accessed / hw.hbm_bw

@@ -241,11 +241,11 @@ def batch_specs(st: Strategy, batch) -> Any:
 
 
 def _paged_pool_spec(st: Strategy, shape: tuple[int, ...]) -> P:
-    """Slot-shared page pools: ``(count, n_pages, page, kv_heads,
-    head_dim)`` leaves (or the per-layer 4-dim view inside a group scan).
-    The page axes are allocator-owned — any physical page may map into
-    any slot's table, and the host rewrites the page table every step —
-    so they must stay replicated; only the trailing *head* axes shard.
+    """Slot-shared head-major page pools: ``(count, n_pages, kv_heads,
+    page, head_dim)`` leaves (or the per-layer 4-dim view inside a group
+    scan). The page axes are allocator-owned — any physical page may map
+    into any slot's table, and the host rewrites the page table every
+    step — so they must stay replicated; only the *head* axes shard.
     kv_heads on the model axis is classic head-parallel attention;
     head_dim is the fallback for GQA head counts the mesh doesn't
     divide. Data axes replicate: data parallelism over serving traffic
@@ -254,8 +254,8 @@ def _paged_pool_spec(st: Strategy, shape: tuple[int, ...]) -> P:
     in-jit constraint and the buffer sharding pin the same layout."""
     ent: list[Any] = [None] * len(shape)
     ma = st.model_axis
-    if ma and len(shape) >= 2:
-        for d in (len(shape) - 2, len(shape) - 1):
+    if ma and len(shape) >= 3:
+        for d in (len(shape) - 3, len(shape) - 1):
             if _fits(st.mesh, shape[d], ma) and shape[d] >= _axsize(
                 st.mesh, ma
             ):
@@ -271,7 +271,7 @@ def cache_specs(st: Strategy, caches, *, layout: str = "decode") -> Any:
     reshard around every dynamic_update_slice.
 
     ``layout="paged"`` switches to the serving engine's slot-shared page
-    pools, whose leaves are (count, n_pages, page, kv_heads, head_dim)
+    pools, whose leaves are (count, n_pages, kv_heads, page, head_dim)
     with no batch dim at all — see ``_paged_pool_spec``."""
     mesh = st.mesh
     if layout == "paged":

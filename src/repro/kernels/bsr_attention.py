@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
 __all__ = ["block_sparse_attention_pallas"]
 
 _NEG_INF = float(jnp.finfo(jnp.float32).min)
@@ -159,7 +157,7 @@ def block_sparse_attention_pallas(
         ),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
     )(kv_index, valid, q, k, v)

@@ -32,8 +32,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
 __all__ = ["bsr_matmul_pallas"]
 
 
@@ -106,7 +104,7 @@ def bsr_matmul_pallas(
         ),
         out_shape=jax.ShapeDtypeStruct((B, nb_out * b), out_dtype),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
     )(cols, x, blocks)

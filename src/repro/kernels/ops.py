@@ -24,7 +24,7 @@ from repro.kernels.bsr_matmul import bsr_matmul_pallas
 
 __all__ = [
     "default_impl",
-    "paged_impl_for_mesh",
+    "impl_for_mesh",
     "bsr_matmul",
     "block_sparse_attention",
 ]
@@ -38,17 +38,16 @@ def default_impl() -> str:
     return "pallas" if platform == "tpu" else "gather"
 
 
-def paged_impl_for_mesh(impl: str, tp_size: int) -> str:
-    """Clamp the paged-attention impl for a tensor-parallel mesh.
+def impl_for_mesh(impl: str | None, n_devices: int) -> str | None:
+    """Clamp a kernel impl (None = the platform default) for a mesh.
 
-    The Pallas page-pool kernel has no SPMD partitioning rule — under
-    GSPMD a pallas_call on sharded operands would force a full
-    all-gather of the KV pools onto every device (or need a shard_map
-    port, a follow-up once real multi-chip TPU is available). The jnp
-    gather path is built from ops GSPMD partitions natively, so sharded
-    pools always take it; single-device meshes keep the requested impl.
+    A Pallas TPU kernel has no SPMD partitioning rule: XLA refuses to
+    partition a Mosaic call over sharded operands ("wrap the call in a
+    shard_map"). The jnp gather paths are built from ops GSPMD
+    partitions natively, so an engine spanning several devices takes
+    them; a single-device mesh keeps the requested impl.
     """
-    if tp_size > 1 and impl in ("pallas", "interpret"):
+    if n_devices > 1 and impl in (None, "pallas", "interpret"):
         return "gather"
     return impl
 

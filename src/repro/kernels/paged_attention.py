@@ -19,6 +19,11 @@ Layout and masking:
   - q is pre-grouped ``(B, Hk, G, D)`` (GQA: G query heads share one
     kv head); each grid step contracts the (G, D) query block with one
     (page, D) pool page.
+  - the pools are head-major ``(n_pages, Hk, page, D)``, so one grid
+    step's K/V block ``(1, 1, page, D)`` is a whole ``(page, D)`` tile.
+    A page-major pool ``(n_pages, page, Hk, D)`` would need a size-1
+    block on its Hk axis, which the TPU compiler refuses (the last two
+    block dims must be multiples of (8, 128) or the full array dims).
   - ``logical`` carries the *logical* block id of every schedule slot:
     key position ``logical * page + offset`` is masked against the
     slot's current position, which also neutralizes the shared trash
@@ -37,8 +42,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels._compat import CompilerParams as _CompilerParams
 
 __all__ = ["paged_decode_attention_pallas"]
 
@@ -74,8 +77,8 @@ def _kernel(
     @pl.when(keep_ref[b, t] == 1)
     def _visit():
         q = q_ref[0, 0]  # (G, D)
-        k = k_ref[0, :, 0, :]  # (page, D)
-        v = v_ref[0, :, 0, :]
+        k = k_ref[0, 0]  # (page, D)
+        v = v_ref[0, 0]
         s = (
             jnp.dot(q, k.T, preferred_element_type=jnp.float32) * sm_scale
         )  # (G, page)
@@ -118,14 +121,14 @@ def paged_decode_attention_pallas(
     sm_scale: float,
     interpret: bool = False,
 ) -> jax.Array:
-    """q: (B, Hk, G, D). k_pages, v_pages: (n_pages, page, Hk, D) pools.
+    """q: (B, Hk, G, D). k_pages, v_pages: (n_pages, Hk, page, D) pools.
 
     phys/logical/keep: (B, w) int32 — physical page id, logical block id
     and keep flag per schedule slot; pos: (B,) int32 current token
     position per slot. Returns (B, Hk, G, D) in q's dtype.
     """
     b, hk, g, d = q.shape
-    _, page, hk_p, d_p = k_pages.shape
+    _, hk_p, page, d_p = k_pages.shape
     if (hk_p, d_p) != (hk, d):
         raise ValueError("pool head/dim mismatch with q")
     if phys.shape != logical.shape or phys.shape != keep.shape:
@@ -140,7 +143,7 @@ def paged_decode_attention_pallas(
 
     def kv_map(bi, hi, t, phys_ref, logical_ref, keep_ref, pos_ref):
         del logical_ref, keep_ref, pos_ref
-        return (phys_ref[bi, t], 0, hi, 0)
+        return (phys_ref[bi, t], hi, 0, 0)
 
     kernel = functools.partial(_kernel, w=w, page=page, sm_scale=sm_scale)
     return pl.pallas_call(
@@ -150,8 +153,8 @@ def paged_decode_attention_pallas(
             grid=grid,
             in_specs=[
                 pl.BlockSpec((1, 1, g, d), q_map),
-                pl.BlockSpec((1, page, 1, d), kv_map),
-                pl.BlockSpec((1, page, 1, d), kv_map),
+                pl.BlockSpec((1, 1, page, d), kv_map),
+                pl.BlockSpec((1, 1, page, d), kv_map),
             ],
             out_specs=pl.BlockSpec((1, 1, g, d), q_map),
             scratch_shapes=[
@@ -162,7 +165,7 @@ def paged_decode_attention_pallas(
         ),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
     )(

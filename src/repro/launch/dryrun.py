@@ -183,11 +183,10 @@ def run_cell(
 
     mem = compiled.memory_analysis()
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, list):  # jax 0.4.x: one dict per program
-        ca = ca[0] if ca else {}
     hlo = compiled.as_text()
     cost = roofline.analyze_hlo(hlo)
-    terms = roofline.roofline_terms(cost)
+    # the production mesh is a v5e pod (make_production_mesh)
+    terms = roofline.roofline_terms(cost, roofline.peaks_for("TPU v5 lite"))
 
     n_tokens = (
         shape.global_batch * shape.seq_len

@@ -27,6 +27,7 @@ import numpy as np
 
 from repro.configs import registry
 from repro.distributed import sharding
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import (
     make_local_mesh,
     make_production_mesh,
@@ -243,6 +244,7 @@ def main():
                          "on step-time spikes, post-warmup compiles and "
                          "SLO CRITICAL transitions")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = (
         registry.get_smoke(args.arch, sparse=args.sparse)

@@ -14,6 +14,7 @@ import argparse
 import jax
 
 from repro.configs import registry
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_local_mesh, make_production_mesh
 from repro.training.data import EmbedsWrapper, SyntheticLM, TextFileLM
 from repro.training.loop import TrainConfig, Trainer
@@ -55,6 +56,7 @@ def main():
     ap.add_argument("--production-mesh", action="store_true")
     ap.add_argument("--compress-grads", action="store_true")
     args = ap.parse_args()
+    use_compile_cache()
 
     if args.smoke:
         cfg = registry.get_smoke(args.arch, sparse=args.sparse)

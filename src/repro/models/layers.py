@@ -36,8 +36,8 @@ def constrain(cfg: ModelConfig, x: jax.Array, *spec) -> jax.Array:
 
 
 def paged_pool_entry(cfg: ModelConfig, hk: int, d: int) -> int | None:
-    """Which of a paged KV pool leaf's two trailing head axes the model
-    axis shards: -2 (kv_heads) preferred, -1 (head_dim) fallback, None
+    """Which of a head-major paged KV pool leaf's head axes the model
+    axis shards: -3 (kv_heads) preferred, -1 (head_dim) fallback, None
     when neither divides (or no TP). The page axes always replicate —
     the host rewrites the page table every step, so any page must be
     addressable from any slot. Must agree with
@@ -47,7 +47,7 @@ def paged_pool_entry(cfg: ModelConfig, hk: int, d: int) -> int | None:
     if tp <= 1:
         return None
     if hk % tp == 0 and hk >= tp:
-        return -2
+        return -3
     if d % tp == 0 and d >= tp:
         return -1
     return None
@@ -55,9 +55,9 @@ def paged_pool_entry(cfg: ModelConfig, hk: int, d: int) -> int | None:
 
 def constrain_paged_pool(cfg: ModelConfig, buf: jax.Array) -> jax.Array:
     """Pin a slot-shared page pool's sharding inside a jit'd step: leaves
-    are (..., page, kv_heads, head_dim); one head axis shards on the
+    are (..., kv_heads, page, head_dim); one head axis shards on the
     model axis per ``paged_pool_entry``, everything else replicates."""
-    ax = paged_pool_entry(cfg, buf.shape[-2], buf.shape[-1])
+    ax = paged_pool_entry(cfg, buf.shape[-3], buf.shape[-1])
     if ax is None:
         return buf
     spec: list = [None] * buf.ndim
@@ -564,6 +564,17 @@ def paged_sparse_schedule(
     return idx, phys, keep
 
 
+def gather_pages(pool: jax.Array, rows: jax.Array) -> jax.Array:
+    """Read pages ``rows`` (B, n) out of a head-major pool (n_pages, Hk,
+    page, D) into a contiguous (B, n * page, Hk, D) key/value sequence
+    in logical order — the portable read the Pallas kernel is checked
+    against."""
+    b, n = rows.shape
+    _, hk, page, d = pool.shape
+    g = jnp.take(pool, rows, axis=0)  # (B, n, Hk, page, D)
+    return g.swapaxes(2, 3).reshape(b, n * page, hk, d)
+
+
 def _paged_attention_kernel(
     q: jax.Array,
     k_pages: jax.Array,
@@ -605,7 +616,7 @@ def paged_decode_attention_jnp(
 ) -> jax.Array:
     """Decode against a block-paged KV cache (dense over logical pages).
 
-    q (B,1,Hk,G,D); k_pages, v_pages (n_pages, page, Hk, D); page_table
+    q (B,1,Hk,G,D); k_pages, v_pages (n_pages, Hk, page, D); page_table
     (B, P) int32 physical page per logical page; pos (B,) int32 position of
     the *current* token per slot. Unallocated table entries point at the
     shared trash page 0 — their keys land beyond ``pos`` and are masked.
@@ -617,7 +628,7 @@ def paged_decode_attention_jnp(
     if impl not in (None, "gather", "pallas", "interpret"):
         raise ValueError(f"unknown impl {impl!r}")
     b = q.shape[0]
-    _, page, hk, d = k_pages.shape
+    page = k_pages.shape[2]
     np_ = page_table.shape[1]
     if impl in ("pallas", "interpret"):
         logical = jnp.broadcast_to(
@@ -628,8 +639,8 @@ def paged_decode_attention_jnp(
             q, k_pages, v_pages, logical, page_table, keep, pos,
             sm_scale=sm_scale, impl=impl,
         )
-    kg = jnp.take(k_pages, page_table, axis=0).reshape(b, np_ * page, hk, d)
-    vg = jnp.take(v_pages, page_table, axis=0).reshape(b, np_ * page, hk, d)
+    kg = gather_pages(k_pages, page_table)
+    vg = gather_pages(v_pages, page_table)
     s = _grouped_logits(q, kg) * sm_scale  # (B,Hk,G,1,S)
     ok = jnp.arange(np_ * page)[None, :] <= pos[:, None]  # logical order
     s = jnp.where(ok[:, None, None, None, :], s, -jnp.inf)
@@ -659,7 +670,7 @@ def paged_sparse_decode_attention_jnp(
     if impl not in (None, "gather", "pallas", "interpret"):
         raise ValueError(f"unknown impl {impl!r}")
     b = q.shape[0]
-    _, page, hk, d = k_pages.shape
+    page = k_pages.shape[2]
     idx, phys, keep = paged_sparse_schedule(
         page_table, pos, page,
         local_blocks=local_blocks, global_blocks=global_blocks,
@@ -669,9 +680,8 @@ def paged_sparse_decode_attention_jnp(
             q, k_pages, v_pages, idx, phys, keep, pos,
             sm_scale=sm_scale, impl=impl,
         )
-    w = idx.shape[1]
-    kg = jnp.take(k_pages, phys, axis=0).reshape(b, w * page, hk, d)
-    vg = jnp.take(v_pages, phys, axis=0).reshape(b, w * page, hk, d)
+    kg = gather_pages(k_pages, phys)
+    vg = gather_pages(v_pages, phys)
     s = _grouped_logits(q, kg) * sm_scale
     kpos = (
         idx[:, :, None] * page + jnp.arange(page)[None, None, :]
@@ -774,7 +784,7 @@ def apply_attention(
     """Returns (y, new_cache). x: (B, S, D) [S=1 for decode].
 
     Paged modes: ``cache`` holds slot-shared page pools ``k``/``v`` of shape
-    (n_pages, page, Hk, D), ``pos`` is per-slot (B,), and ``page_table``
+    (n_pages, Hk, page, D), ``pos`` is per-slot (B,), and ``page_table``
     (B, P) maps each slot's logical pages to physical ones. ``paged_impl``
     selects the paged decode read: None/"gather" portable jnp gathers, or
     "pallas"/"interpret" for the fused page-pool kernel.
@@ -813,14 +823,8 @@ def apply_attention(
         # suffix K/V is returned for the caller's page scatter, the
         # shared prefix pages are read-only.
         assert cache is not None and pos is not None and page_table is not None
-        page = cache["k"].shape[1]
-        npre = page_table.shape[1]
-        kp = jnp.take(cache["k"], page_table, axis=0).reshape(
-            b, npre * page, hk, d
-        )
-        vp = jnp.take(cache["v"], page_table, axis=0).reshape(
-            b, npre * page, hk, d
-        )
+        kp = gather_pages(cache["k"], page_table)
+        vp = gather_pages(cache["v"], page_table)
         block_cfg = (
             (
                 c.attn_block,
@@ -843,14 +847,14 @@ def apply_attention(
             o = constrain(c, o, *aspec["o"])
     elif mode in ("decode_paged", "decode_paged_sparse"):
         assert cache is not None and pos is not None and page_table is not None
-        page = cache["k"].shape[1]
+        page = cache["k"].shape[2]
         # tensor-parallel decode: head-partition the fresh K/V and the
         # grouped queries on the same axis the pool shards, so the write
         # scatter and the attention read stay shard-local (the only
         # collective left is wo's psum).
         pool_ax = paged_pool_entry(c, hk, d)
         ba = c.batch_axes or None
-        if pool_ax == -2:
+        if pool_ax == -3:
             qg = constrain(c, qg, ba, None, "model", None, None)
             k = constrain(c, k, ba, None, "model", None)
             v = constrain(c, v, ba, None, "model", None)
@@ -863,8 +867,8 @@ def apply_attention(
         phys = jnp.take_along_axis(page_table, (pos // page)[:, None], axis=1)
         phys = phys[:, 0]
         off = pos % page
-        kc = cache["k"].at[phys, off].set(k[:, 0].astype(cache["k"].dtype))
-        vc = cache["v"].at[phys, off].set(v[:, 0].astype(cache["v"].dtype))
+        kc = cache["k"].at[phys, :, off].set(k[:, 0].astype(cache["k"].dtype))
+        vc = cache["v"].at[phys, :, off].set(v[:, 0].astype(cache["v"].dtype))
         kc = constrain_paged_pool(c, kc)
         vc = constrain_paged_pool(c, vc)
         new_cache = {"k": kc, "v": vc}

@@ -239,13 +239,16 @@ def init_paged_cache(
 ) -> list:
     """Slot-shared page pools, one per layer group (stacked over layers).
 
-    Physical page 0 is reserved as the trash page (idle slots and
-    unallocated table entries point at it); the serving allocator hands
-    out pages 1..n_pages-1. ``shardings`` (a matching tree of
-    ``NamedSharding``, built from ``sharding.cache_specs(layout="paged")``)
-    places each pool across the mesh at creation — head axes sharded on
-    the model axis, page axes replicated — so tensor-parallel decode
-    never starts from a single-device pool.
+    Leaves are head-major ``(count, n_pages, kv_heads, page, head_dim)``:
+    one (page, head_dim) tile per page and head, which is the block the
+    Pallas paged-decode kernel reads. Physical page 0 is reserved as the
+    trash page (idle slots and unallocated table entries point at it);
+    the serving allocator hands out pages 1..n_pages-1. ``shardings`` (a
+    matching tree of ``NamedSharding``, built from
+    ``sharding.cache_specs(layout="paged")``) places each pool across the
+    mesh at creation — head axes sharded on the model axis, page axes
+    replicated — so tensor-parallel decode never starts from a
+    single-device pool.
     """
     caches = []
     for g in cfg.layer_groups():
@@ -257,11 +260,11 @@ def init_paged_cache(
         caches.append(
             {
                 "k": jnp.zeros(
-                    (g.count, n_pages, page, cfg.num_kv_heads, cfg.head_dim),
+                    (g.count, n_pages, cfg.num_kv_heads, page, cfg.head_dim),
                     cfg.jdtype,
                 ),
                 "v": jnp.zeros(
-                    (g.count, n_pages, page, cfg.num_kv_heads, cfg.head_dim),
+                    (g.count, n_pages, cfg.num_kv_heads, page, cfg.head_dim),
                     cfg.jdtype,
                 ),
             }
@@ -513,8 +516,8 @@ def prefill_paged(
     new_caches = []
     for pool, fresh in zip(caches, kv):
         def scat(buf, kvs):
-            count, _, page, hk, d = buf.shape
-            fb = kvs.reshape(count, b, s // page, page, hk, d)
+            count, _, hk, page, d = buf.shape
+            fb = kvs.reshape(count, b, s // page, page, hk, d).swapaxes(3, 4)
             # page_rows (N, P): scatter every request's pages in one shot.
             # Rows collide only on the shared trash page 0 (padding), where
             # last-write-wins is fine — trash is masked by logical position

@@ -300,12 +300,14 @@ class Engine:
                     f"unknown paged_impl {paged_impl!r}; expected "
                     "'gather', 'pallas' or 'interpret'"
                 )
-            from repro.kernels.ops import paged_impl_for_mesh
+            from repro.kernels.ops import impl_for_mesh
 
-            # sharded pools force the gather path: the Pallas kernel has
-            # no SPMD partitioning rule (see kernels.ops)
-            paged_impl = paged_impl_for_mesh(paged_impl, st.tp_size)
+            # an engine over several devices takes the gather paths for
+            # paged decode and the BSR projections alike: a Pallas call
+            # has no SPMD partitioning rule (see kernels.ops)
+            paged_impl = impl_for_mesh(paged_impl, mesh.size)
             self.paged_impl = paged_impl
+            impl = impl_for_mesh(None, mesh.size)
             # Slot-indexed sampling state. The host-side (slots,) param
             # rows are written at admission; each step packs them into
             # device arrays so the sampler runs INSIDE the jit'd step —
@@ -345,14 +347,15 @@ class Engine:
             self._decode = jax.jit(
                 lambda p, c, t, pos, pt: _argmax_first(
                     T.decode_step_paged(
-                        cfg, p, c, t, pos, pt, paged_impl=paged_impl
+                        cfg, p, c, t, pos, pt, impl=impl,
+                        paged_impl=paged_impl,
                     )
                 ),
                 donate_argnums=(1,),
             )
             self._decode_sampled = jax.jit(
                 lambda p, c, t, pos, pt, samp, pres: T.decode_step_paged(
-                    cfg, p, c, t, pos, pt, paged_impl=paged_impl,
+                    cfg, p, c, t, pos, pt, impl=impl, paged_impl=paged_impl,
                     sampler={
                         **samp,
                         # per-request sample index, derived in-jit: the
@@ -368,13 +371,13 @@ class Engine:
             # one wrapper; jax.jit specializes per (N, S) bucket shape
             self._prefill = jax.jit(
                 lambda p, t, plens, c, rows: _argmax_first(
-                    T.prefill_paged(cfg, p, t, plens, c, rows)
+                    T.prefill_paged(cfg, p, t, plens, c, rows, impl=impl)
                 ),
                 donate_argnums=(3,),
             )
             self._prefill_sampled = jax.jit(
                 lambda p, t, plens, c, rows, samp, pres: T.prefill_paged(
-                    cfg, p, t, plens, c, rows,
+                    cfg, p, t, plens, c, rows, impl=impl,
                     sampler={**samp, "presence": pres},
                     sampler_candidates=ecfg.sampler_candidates,
                 ),
@@ -388,7 +391,7 @@ class Engine:
             self._prefill_pre = jax.jit(
                 lambda p, t, plens, c, rows, prow, plen_pre: _argmax_first(
                     T.prefill_paged(
-                        cfg, p, t, plens, c, rows,
+                        cfg, p, t, plens, c, rows, impl=impl,
                         prefix_rows=prow, prefix_lens=plen_pre,
                     )
                 ),
@@ -397,7 +400,7 @@ class Engine:
             self._prefill_pre_sampled = jax.jit(
                 lambda p, t, plens, c, rows, prow, plen_pre, ft, fl, samp, pres: (
                     T.prefill_paged(
-                        cfg, p, t, plens, c, rows,
+                        cfg, p, t, plens, c, rows, impl=impl,
                         prefix_rows=prow, prefix_lens=plen_pre,
                         full_tokens=ft, full_plens=fl,
                         sampler={**samp, "presence": pres},
@@ -1598,53 +1601,27 @@ class Engine:
         """Roofline terms for the compiled decode step (lazy, cached
         per engine): FLOPs and HBM bytes parsed from the optimized HLO
         (``repro.analysis.roofline``), per-device time lower bounds and
-        the dominant bottleneck. Costs one extra AOT compile of the
-        decode program on first call; degrades to ``available: False``
-        zeros when the backend can't produce analyzable HLO."""
+        the dominant bottleneck, against the peaks of this engine's
+        ``device_kind``. Costs one extra AOT compile of the decode
+        program on first call. A device without an entry in
+        ``roofline.PEAKS`` reports ``available: False`` with the reason;
+        any other failure raises."""
         if self._roofline is not None:
             return self._roofline
-        try:
-            from repro.analysis.roofline import analyze_hlo, roofline_terms
+        from repro.analysis.roofline import (
+            analyze_hlo,
+            peaks_for,
+            roofline_terms,
+        )
 
-            zeros = jnp.zeros((self.ecfg.max_slots,), jnp.int32)
-            table0 = jnp.zeros_like(jnp.asarray(self.kv.page_table))
-            with self.mesh:
-                txt = (
-                    self._decode.lower(
-                        self.params, self.kv.buffers, zeros, zeros, table0
-                    )
-                    .compile()
-                    .as_text()
-                )
-            cost = analyze_hlo(txt)
-            terms = roofline_terms(cost)
-            self._roofline = {
-                "available": True,
-                "flops": cost.flops,
-                "bytes_accessed": cost.bytes_accessed,
-                "collective_bytes": cost.total_collective_bytes,
-                "arithmetic_intensity": round(
-                    cost.flops / cost.bytes_accessed, 4
-                )
-                if cost.bytes_accessed
-                else 0.0,
-                "bottleneck": terms["bottleneck"],
-                "compute_s": terms["compute_s"],
-                "memory_s": terms["memory_s"],
-                "collective_s": terms["collective_s"],
-            }
-            # annotate the trace: a0 = intensity x1000, a1 = bottleneck
-            self.tracer.instant(
-                self._tk_decode,
-                self._nm_roofline,
-                int(self._roofline["arithmetic_intensity"] * 1000),
-                {"compute": 0, "memory": 1, "collective": 2}.get(
-                    terms["bottleneck"], 3
-                ),
-            )
-        except Exception:
+        kind = np.asarray(self.mesh.devices).flat[0].device_kind
+        try:
+            hw = peaks_for(kind)
+        except KeyError as e:
             self._roofline = {
                 "available": False,
+                "reason": str(e.args[0]),
+                "device_kind": kind,
                 "flops": 0.0,
                 "bytes_accessed": 0.0,
                 "collective_bytes": 0.0,
@@ -1654,6 +1631,44 @@ class Engine:
                 "memory_s": 0.0,
                 "collective_s": 0.0,
             }
+            return self._roofline
+        zeros = jnp.zeros((self.ecfg.max_slots,), jnp.int32)
+        table0 = jnp.zeros_like(jnp.asarray(self.kv.page_table))
+        with self.mesh:
+            txt = (
+                self._decode.lower(
+                    self.params, self.kv.buffers, zeros, zeros, table0
+                )
+                .compile()
+                .as_text()
+            )
+        cost = analyze_hlo(txt)
+        terms = roofline_terms(cost, hw)
+        self._roofline = {
+            "available": True,
+            "device_kind": kind,
+            "flops": cost.flops,
+            "bytes_accessed": cost.bytes_accessed,
+            "collective_bytes": cost.total_collective_bytes,
+            "arithmetic_intensity": round(
+                cost.flops / cost.bytes_accessed, 4
+            )
+            if cost.bytes_accessed
+            else 0.0,
+            "bottleneck": terms["bottleneck"],
+            "compute_s": terms["compute_s"],
+            "memory_s": terms["memory_s"],
+            "collective_s": terms["collective_s"],
+        }
+        # annotate the trace: a0 = intensity x1000, a1 = bottleneck
+        self.tracer.instant(
+            self._tk_decode,
+            self._nm_roofline,
+            int(self._roofline["arithmetic_intensity"] * 1000),
+            {"compute": 0, "memory": 1, "collective": 2}.get(
+                terms["bottleneck"], 3
+            ),
+        )
         return self._roofline
 
     def _finish(

@@ -28,9 +28,9 @@ import dataclasses
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
 
 from repro.configs.base import ModelConfig
+from repro.launch.mesh import make_tp_mesh
 from repro.obs import MetricsRegistry, export_perfetto
 from repro.serving.engine import Engine, EngineConfig
 from repro.serving.prefix import PrefixStats
@@ -72,8 +72,7 @@ class ReplicaRouter:
         self.tp = tp
         self.engines: list[Engine] = []
         for r in range(replicas):
-            sub = np.asarray(devices[r * tp : (r + 1) * tp]).reshape(1, tp)
-            mesh = Mesh(sub, ("data", "model"))
+            mesh = make_tp_mesh(tp, devices[r * tp : (r + 1) * tp])
             self.engines.append(
                 Engine(
                     cfg,

@@ -51,7 +51,7 @@ __all__ = ["SwapManager", "SwapRecord", "SwapStats"]
 @jax.jit
 def _gather_pages(buffers, idx: jax.Array):
     """Pull pages ``idx`` out of every layer pool into standalone
-    (layers, n, page, kv_heads, head_dim) staging arrays."""
+    (layers, n, kv_heads, page, head_dim) staging arrays."""
     return jax.tree.map(lambda b: b[:, idx], buffers)
 
 

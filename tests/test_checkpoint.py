@@ -64,7 +64,10 @@ def test_elastic_restore_on_new_sharding(tmp_path):
     d = str(tmp_path)
     ck.save(d, 1, _tree(3.0))
     import jax
-    mesh = jax.make_mesh((1,), ("data",))
+
+    from repro.launch.mesh import make_mesh
+
+    mesh = make_mesh((1,), ("data",))
     sh = jax.tree.map(
         lambda _: jax.NamedSharding(mesh, jax.sharding.PartitionSpec()),
         _tree(),

@@ -27,8 +27,8 @@ def _scenario(b, hk, d, *, seed=0, dtype=jnp.float32, trash_slot=True,
     and an idle slot whose row is all trash page."""
     rng = np.random.default_rng(seed)
     n_pages = b * PPS + 1
-    k = rng.standard_normal((n_pages, PAGE, hk, d))
-    v = rng.standard_normal((n_pages, PAGE, hk, d))
+    k = rng.standard_normal((n_pages, hk, PAGE, d))  # head-major pools
+    v = rng.standard_normal((n_pages, hk, PAGE, d))
     # poison the trash page: an unmasked read of page 0 shows up as a
     # huge output delta instead of hiding inside the tolerance
     k[0] = 1e4
@@ -143,8 +143,12 @@ def test_dense_kernel_matches_contiguous_cache_oracle():
     )
     tbl = np.asarray(table)
     for s in range(b):
-        kc = jnp.asarray(np.asarray(k)[tbl[s]].reshape(1, PPS * PAGE, hk, D))
-        vc = jnp.asarray(np.asarray(v)[tbl[s]].reshape(1, PPS * PAGE, hk, D))
+        kc = jnp.asarray(
+            np.asarray(k)[tbl[s]].swapaxes(1, 2).reshape(1, PPS * PAGE, hk, D)
+        )
+        vc = jnp.asarray(
+            np.asarray(v)[tbl[s]].swapaxes(1, 2).reshape(1, PPS * PAGE, hk, D)
+        )
         want = L.decode_attention_jnp(
             q[s:s + 1], kc, vc, pos[s], sm_scale=D ** -0.5
         )
@@ -160,8 +164,8 @@ def test_sparse_kernel_covering_schedule_equals_dense():
     rng = np.random.default_rng(5)
     b, hk, g, pps = 3, 2, 2, 2
     n_pages = b * pps + 1
-    k = rng.standard_normal((n_pages, PAGE, hk, D))
-    v = rng.standard_normal((n_pages, PAGE, hk, D))
+    k = rng.standard_normal((n_pages, hk, PAGE, D))
+    v = rng.standard_normal((n_pages, hk, PAGE, D))
     k[0], v[0] = 1e4, -1e4
     perm = rng.permutation(np.arange(1, n_pages))
     table = jnp.asarray(perm.reshape(b, pps).astype(np.int32))

@@ -14,10 +14,11 @@ invariant.
 import jax
 import numpy as np
 import pytest
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
 
 from repro.configs import registry
 from repro.distributed import sharding
+from repro.launch.mesh import make_tp_mesh
 from repro.serving import Engine, EngineConfig
 from repro.serving.router import ReplicaRouter
 from repro.serving.sampling import SamplingParams
@@ -30,17 +31,7 @@ requires_mesh = pytest.mark.skipif(
 
 
 def _abstract_mesh(data: int, model: int):
-    try:
-        return jax.sharding.AbstractMesh((data, model), ("data", "model"))
-    except TypeError:  # jax 0.4.x: tuple of (name, size) pairs
-        return jax.sharding.AbstractMesh(
-            (("data", data), ("model", model))
-        )
-
-
-def _sub_mesh(k: int) -> Mesh:
-    sub = np.asarray(jax.devices()[:k]).reshape(1, k)
-    return Mesh(sub, ("data", "model"))
+    return jax.sharding.AbstractMesh((data, model), ("data", "model"))
 
 
 # ----------------------------------------------------------------------
@@ -52,23 +43,23 @@ def test_paged_cache_specs_shard_kv_heads_when_divisible():
     st = sharding.Strategy(_abstract_mesh(1, 2), "tp")
     pools = [
         {
-            "k": jax.ShapeDtypeStruct((3, 9, 64, 4, 64), np.float32),
-            "v": jax.ShapeDtypeStruct((3, 9, 64, 4, 64), np.float32),
+            "k": jax.ShapeDtypeStruct((3, 9, 4, 64, 64), np.float32),
+            "v": jax.ShapeDtypeStruct((3, 9, 4, 64, 64), np.float32),
         }
     ]
     specs = sharding.cache_specs(st, pools, layout="paged")
-    assert specs[0]["k"] == P(None, None, None, "model", None)
-    assert specs[0]["v"] == P(None, None, None, "model", None)
+    assert specs[0]["k"] == P(None, None, "model", None, None)
+    assert specs[0]["v"] == P(None, None, "model", None, None)
 
 
 def test_paged_cache_specs_head_dim_fallback_and_replication():
     st = sharding.Strategy(_abstract_mesh(1, 8), "tp")
     # kv_heads=2 does not divide tp=8; head_dim=64 does
-    pool = {"k": jax.ShapeDtypeStruct((2, 9, 64, 2, 64), np.float32)}
+    pool = {"k": jax.ShapeDtypeStruct((2, 9, 2, 64, 64), np.float32)}
     specs = sharding.cache_specs(st, [pool], layout="paged")
     assert specs[0]["k"] == P(None, None, None, None, "model")
     # neither head axis divisible -> fully replicated (never the page axes)
-    pool = {"k": jax.ShapeDtypeStruct((2, 16, 8, 3, 5), np.float32)}
+    pool = {"k": jax.ShapeDtypeStruct((2, 16, 3, 8, 5), np.float32)}
     specs = sharding.cache_specs(st, [pool], layout="paged")
     assert specs[0]["k"] == P(None, None, None, None, None)
 
@@ -77,7 +68,7 @@ def test_paged_cache_specs_fsdp_replicates():
     # fsdp strategy has no model axis: pools replicate, page axes and
     # head axes alike (DP is replica routing, not a sharded pool)
     st = sharding.Strategy(_abstract_mesh(4, 2), "fsdp")
-    pool = {"k": jax.ShapeDtypeStruct((2, 9, 64, 4, 64), np.float32)}
+    pool = {"k": jax.ShapeDtypeStruct((2, 9, 4, 64, 64), np.float32)}
     specs = sharding.cache_specs(st, [pool], layout="paged")
     assert specs[0]["k"] == P(None, None, None, None, None)
 
@@ -88,6 +79,17 @@ def test_cache_specs_decode_layout_unchanged():
     caches = [{"k": jax.ShapeDtypeStruct((2, 4, 128, 4, 64), np.float32)}]
     specs = sharding.cache_specs(st, caches)
     assert specs[0]["k"][1] is not None  # batch dim sharded over data
+
+
+def test_multi_device_mesh_takes_gather_paths():
+    # a Pallas call cannot be partitioned over sharded operands: any
+    # mesh of more than one device clamps every kernel impl to gather,
+    # while one device keeps the platform default (None) or the request
+    from repro.kernels.ops import impl_for_mesh
+
+    for impl in (None, "pallas", "interpret", "gather"):
+        assert impl_for_mesh(impl, 4) == "gather"
+        assert impl_for_mesh(impl, 1) == impl
 
 
 def test_unknown_cache_layout_raises():
@@ -118,7 +120,7 @@ def _prompts(n=4):
 def _run_engine(tp: int, sampled: bool, cfg, params=None):
     eng = Engine(
         cfg,
-        _sub_mesh(tp),
+        make_tp_mesh(tp),
         engine_cfg=EngineConfig(max_slots=4, max_len=64, prefix_cache=True),
         strategy="tp",
         seed=0,
@@ -152,8 +154,8 @@ def test_tp_pool_buffers_actually_sharded():
     _, eng = _run_engine(2, False, cfg)
     spec = tuple(eng.kv.buffers[0]["k"].sharding.spec)
     spec = spec + (None,) * (5 - len(spec))  # jax trims trailing Nones
-    # smoke kv_heads=2 divides tp=2: classic head sharding on axis 3
-    assert spec == (None, None, None, "model", None)
+    # smoke kv_heads=2 divides tp=2: classic head sharding on axis 2
+    assert spec == (None, None, "model", None, None)
     assert eng.kv.shardings is not None
 
 

@@ -275,14 +275,13 @@ def _mesh_strategy():
     The tiny cfg's head_dim=8 divides tp=8, so the pool's last axis
     shards on the model axis."""
     import jax
-    from jax.sharding import Mesh
 
     from repro.distributed import sharding as shd
+    from repro.launch.mesh import make_tp_mesh
 
     if len(jax.devices()) < 8:
         return None
-    sub = np.asarray(jax.devices()[:8]).reshape(1, 8)
-    return shd.Strategy(Mesh(sub, ("data", "model")), "tp")
+    return shd.Strategy(make_tp_mesh(8), "tp")
 
 
 @pytest.mark.parametrize("seed", range(4))

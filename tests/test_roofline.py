@@ -65,7 +65,7 @@ def test_terms_and_bottleneck():
     cost = roofline.HloCost(
         flops=197e12, bytes_accessed=819e9 / 2, collective_bytes={}, n_collectives=0
     )
-    t = roofline.roofline_terms(cost)
+    t = roofline.roofline_terms(cost, roofline.peaks_for("TPU v5 lite"))
     assert t["bottleneck"] == "compute"
     assert t["compute_s"] == pytest.approx(1.0)
     assert t["memory_s"] == pytest.approx(0.5)
@@ -86,3 +86,8 @@ def test_shape_parse():
     assert e == 256 * 128 and b == 2 * e
     b, e = roofline._shape_info("(s32[], f32[4,4]{1,0})")
     assert e == 1 + 16 and b == 4 + 64
+
+
+def test_unknown_device_has_no_peaks():
+    with pytest.raises(KeyError, match="no peak rates"):
+        roofline.peaks_for("cpu")

@@ -109,8 +109,8 @@ def test_paged_prefill_roundtrips_vs_contiguous_cache():
     )
     for pool, r in zip(kv.buffers, ref):
         for name in ("k", "v"):
-            # gather the slot's page back into (count, S, hk, d)
-            got = np.asarray(pool[name][:, kv.page_table[1, 0]])
+            # gather the slot's head-major page back into (count, S, hk, d)
+            got = np.asarray(pool[name][:, kv.page_table[1, 0]]).swapaxes(1, 2)
             want = np.asarray(r[name][:, 0, :plen])
             np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
 

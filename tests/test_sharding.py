@@ -9,6 +9,7 @@ from jax.sharding import PartitionSpec as P
 from repro.configs import registry
 from repro.distributed import sharding
 from repro.launch import specs as specs_lib
+from repro.launch.mesh import make_mesh
 from repro.training.optimizer import OptConfig
 
 
@@ -16,7 +17,7 @@ def _mesh():
     # 1-device mesh with the production axis names: divisibility logic is
     # exercised against axis sizes of 1 (full mesh runs live in the dryrun
     # process with 512 host devices).
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 @pytest.mark.parametrize("kind", ["tp", "fsdp"])
@@ -54,7 +55,7 @@ def test_shared_attn_unstacked_rule():
 
 
 def test_batch_specs_divisibility_fallback():
-    st = sharding.Strategy(jax.make_mesh((1, 1), ("data", "model")), "fsdp")
+    st = sharding.Strategy(make_mesh((1, 1), ("data", "model")), "fsdp")
     import jax.numpy as jnp
     batch = {"tokens": jax.ShapeDtypeStruct((3, 7), jnp.int32)}
     specs = sharding.batch_specs(st, batch)
@@ -64,10 +65,7 @@ def test_batch_specs_divisibility_fallback():
 
 def test_cache_specs_seq_sharding_for_batch1():
     import jax.numpy as jnp
-    try:  # jax >= 0.5 signature
-        amesh = jax.sharding.AbstractMesh((2, 1), ("data", "model"))
-    except TypeError:  # jax 0.4.x: tuple of (name, size) pairs
-        amesh = jax.sharding.AbstractMesh((("data", 2), ("model", 1)))
+    amesh = jax.sharding.AbstractMesh((2, 1), ("data", "model"))
     st = sharding.Strategy(amesh, "fsdp")
     caches = [
         {"k": jax.ShapeDtypeStruct((4, 1, 1024, 5, 64), jnp.bfloat16)}
@@ -77,7 +75,7 @@ def test_cache_specs_seq_sharding_for_batch1():
 
 
 def test_strategy_axes():
-    mesh = jax.make_mesh((1, 1, 1), ("pod", "data", "model"))
+    mesh = make_mesh((1, 1, 1), ("pod", "data", "model"))
     tp = sharding.Strategy(mesh, "tp")
     assert tp.model_axis == "model" and tp.fsdp == ("pod", "data")
     fs = sharding.Strategy(mesh, "fsdp")

@@ -72,8 +72,6 @@ def test_walker_agrees_with_xla_on_loop_free_programs():
         c = jax.jit(lambda a, b: (a @ b)).lower(a, b).compile()
         walker = roofline.analyze_hlo(c.as_text()).flops
         ca = c.cost_analysis() or {}
-        if isinstance(ca, list):  # jax 0.4.x: one dict per program
-            ca = ca[0] if ca else {}
         xla = ca.get("flops", 0.0)
         assert abs(walker - xla) <= 0.02 * max(walker, xla) + 1, (m, k, n)
 
