@@ -1,0 +1,523 @@
+"""One cell of the benchmark: build the engine the way ``launch/serve.py``
+does, warm the cell's programs, drive an open loop on the wall clock,
+then check the served tokens against the plain reference.
+
+Everything a cell needs is found by name: ``BENCHMARK.json`` names the
+cell's configuration and traffic; ``bench/configs/<config>.json``,
+``bench/traffic/<traffic>.json`` and ``bench/cells/<cell>.json`` hold
+them; each per-layer metric is read by ``bench/metrics/<metric>.py``.
+"""
+
+from __future__ import annotations
+
+import contextlib
+import dataclasses
+import importlib.util
+import json
+import math
+import re
+import time
+from pathlib import Path
+
+import numpy as np
+
+from bench.reference import ModelSpec, Reference
+from bench.traffic import generator
+
+ROOT = Path(__file__).resolve().parents[1]
+# room on the chip beyond what the hungrier program needs: the runtime's
+# own reserve (258 MiB on a v5e), sampling state, page tables
+POOL_RESERVE_BYTES = 512 << 20
+POOL_TRIES = 4
+# reference sample: the longest finished request, then others drawn from
+# the seed until this many served tokens are covered
+SAMPLE_TOKENS = 384
+SAMPLE_MAX_REQUESTS = 8
+
+
+# ----------------------------------------------------------------------
+# Cells
+# ----------------------------------------------------------------------
+
+
+@dataclasses.dataclass
+class Cell:
+    name: str
+    chips: int
+    config: dict
+    mix: generator.Mix
+    cell: dict
+    end_to_end: list[dict]
+    per_layer: list[dict]
+
+
+def load_benchmark(root: Path = ROOT) -> dict:
+    return json.loads((root / "BENCHMARK.json").read_text())
+
+
+def _in_cell(metric: dict, cell: str) -> bool:
+    return "workloads" not in metric or cell in metric["workloads"]
+
+
+def resolve_cell(bench: dict, name: str, root: Path = ROOT) -> Cell:
+    wl = {w["name"]: w for w in bench["workloads"]}
+    if name not in wl:
+        raise KeyError(f"unknown workload {name!r}; known: {sorted(wl)}")
+    w = wl[name]
+    conf = {c["name"]: c for c in bench["configs"]}[w["config"]]
+    return Cell(
+        name=name,
+        chips=int(w["chips"]),
+        config=json.loads((root / conf["file"]).read_text()),
+        mix=generator.load_mix(w["traffic"], root / "bench" / "traffic"),
+        cell=json.loads((root / "bench" / "cells" / f"{name}.json").read_text()),
+        end_to_end=[m for m in bench["end_to_end"] if _in_cell(m, name)],
+        per_layer=[m for m in bench["per_layer"] if _in_cell(m, name)],
+    )
+
+
+def metric_reader(name: str, root: Path = ROOT):
+    """``read(run, trace)`` of ``bench/metrics/<name>.py``: the metric's
+    value, or None where the run offers nothing to read it from."""
+    path = root / "bench" / "metrics" / f"{name}.py"
+    spec = importlib.util.spec_from_file_location(f"bench_metric_{name.replace('.', '_')}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.read
+
+
+def model_spec(conf: dict) -> ModelSpec:
+    """The reference's view of a configuration file (published keys)."""
+    px = conf.get("pixelfly") or {}
+    return ModelSpec(
+        layers=conf["num_hidden_layers"],
+        d_model=conf["hidden_size"],
+        heads=conf["num_attention_heads"],
+        kv_heads=conf["num_key_value_heads"],
+        head_dim=conf["head_dim"],
+        d_ff=conf["intermediate_size"],
+        vocab=conf["vocab_size"],
+        rope_theta=float(conf["rope_theta"]),
+        norm_eps=float(conf["rms_norm_eps"]),
+        sparse=bool(px),
+        density=px.get("sparse_density", 0.2),
+        block=px.get("sparse_block", 128),
+        lowrank_frac=px.get("lowrank_frac", 0.25),
+        attn_block=px.get("attn_block", 128),
+        local_blocks=px.get("attn_local_blocks", 2),
+        global_blocks=px.get("attn_global_blocks", 1),
+    )
+
+
+def program_config(conf: dict, *, smoke: dict | None = None):
+    """The program's ``ModelConfig`` for a configuration file, through
+    its registry, checked against every published width. ``smoke``
+    replaces widths for CPU tests (and the check with it)."""
+    from repro.configs import registry
+
+    px = conf.get("pixelfly") or {}
+    over = {"norm_eps": float(conf["rms_norm_eps"])}
+    if px:
+        over.update(
+            sparse_density=px["sparse_density"],
+            sparse_block=px["sparse_block"],
+            lowrank_frac=px["lowrank_frac"],
+            attn_local_blocks=px["attn_local_blocks"],
+            attn_global_blocks=px["attn_global_blocks"],
+            attn_block=px["attn_block"],
+        )
+    over.update(smoke or {})
+    cfg = registry.get(conf["arch"], sparse=bool(px), **over)
+    want = {
+        "num_layers": conf["num_hidden_layers"],
+        "d_model": conf["hidden_size"],
+        "num_heads": conf["num_attention_heads"],
+        "num_kv_heads": conf["num_key_value_heads"],
+        "head_dim": conf["head_dim"],
+        "d_ff": conf["intermediate_size"],
+        "vocab_size": conf["vocab_size"],
+        "rope_theta": float(conf["rope_theta"]),
+        "qk_norm": True,
+        "tie_embeddings": conf["tie_word_embeddings"],
+        "dtype": conf["torch_dtype"],
+        "sparse_attention": bool(px),
+        "attn_max_stride": 0,
+    }
+    got = {k: getattr(cfg, k) for k in want}
+    if got != want:
+        bad = {k: (got[k], want[k]) for k in want if got[k] != want[k]}
+        raise ValueError(f"registry config differs from the file: {bad}")
+    return cfg
+
+
+# ----------------------------------------------------------------------
+# Engine
+# ----------------------------------------------------------------------
+
+
+def prefill_buckets(mix: generator.Mix, page: int) -> list[tuple[int, int]]:
+    """Every (N, S) prefill program the mix can make the engine run:
+    S the power-of-two page bucket of a prompt length (capped at a
+    slot's pages), N a power of two up to ``max_prefill_batch``."""
+    eng = mix.engine
+    per_seq = eng["max_len"] // page
+    lo = -(-mix.prompt_len["min"] // page)
+    hi = -(-mix.prompt_len["max"] // page)
+    seqs = sorted(
+        {min(1 << (n - 1).bit_length(), per_seq) * page for n in range(lo, hi + 1)}
+    )
+    ns = [1 << i for i in range(eng["max_prefill_batch"].bit_length()) if 1 << i <= eng["max_prefill_batch"]]
+    return [(n, s) for n in ns for s in seqs]
+
+
+def engine_config(mix: generator.Mix, n_pages: int):
+    from repro.serving import EngineConfig
+
+    e = mix.engine
+    return EngineConfig(
+        max_slots=e["max_slots"],
+        max_len=e["max_len"],
+        max_prefill_batch=e["max_prefill_batch"],
+        n_pages=n_pages,
+    )
+
+
+def page_bytes(cfg) -> int:
+    return 2 * cfg.num_layers * cfg.num_kv_heads * cfg.attn_block * cfg.head_dim * np.dtype(cfg.dtype).itemsize
+
+
+def size_pool(cfg, mesh, param_shapes, mix, device) -> dict:
+    """Pages for the pool: what the chip holds after the parameters and
+    the temporaries of the decode step and the largest prefill program,
+    as ``memory_analysis()`` of those programs reports them. Temporaries
+    grow with the pool (the decode step copies it), so the pages are
+    first estimated from two small probe pools, then the programs are
+    compiled at the estimate and it is cut until both fit."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.distributed import sharding
+    from repro.kernels.ops import default_impl
+    from repro.models import transformer as T
+
+    st = sharding.Strategy(mesh, "fsdp")
+    c = cfg.replace(tp_size=st.tp_size, batch_axes=st.batch)
+    page, e = cfg.attn_block, mix.engine
+    per_seq = e["max_len"] // page
+    slots = e["max_slots"]
+    impl = default_impl()
+    sds = jax.ShapeDtypeStruct
+    nb, s = max(prefill_buckets(mix, page), key=lambda b: b[0] * b[1])
+    i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
+    dec = jax.jit(
+        lambda p, cc, t, pos, tab: T.decode_step_paged(c, p, cc, t, pos, tab, impl=impl, paged_impl=impl),
+        donate_argnums=(1,),
+    )
+    pre = jax.jit(
+        lambda p, t, pl, cc, rows: T.prefill_paged(c, p, t, pl, cc, rows, impl=impl),
+        donate_argnums=(3,),
+    )
+
+    def need(n_pages) -> list[int]:
+        """Bytes the device holds while each program runs."""
+        pool = jax.eval_shape(lambda: T.init_paged_cache(c, n_pages, page))
+        with mesh:
+            progs = (
+                dec.lower(param_shapes, pool, i32(slots), i32(slots), i32(slots, per_seq)),
+                pre.lower(param_shapes, i32(nb, s), i32(nb), pool, i32(nb, s // page)),
+            )
+            out = []
+            for lowered in progs:
+                m = lowered.compile().memory_analysis()
+                out.append(m.argument_size_in_bytes + m.temp_size_in_bytes
+                           + m.output_size_in_bytes - m.alias_size_in_bytes)
+        return out
+
+    limit = device.memory_stats()["bytes_limit"]
+    budget = limit - POOL_RESERVE_BYTES
+    params = sum(math.prod(x.shape) * x.dtype.itemsize for x in jax.tree.leaves(param_shapes))
+    lo, hi = per_seq + 1, 2 * per_seq + 1
+    # each program's need is affine in the pages: fit both, take the
+    # tighter, then correct the estimate by what the compiler reports
+    lines = [(a, (b - a) / (hi - lo)) for a, b in zip(need(lo), need(hi))]
+    n = min(int(lo + (budget - a) // slope) for a, slope in lines)
+    tries = []
+    for _ in range(POOL_TRIES):
+        n = max(lo, min(n, slots * per_seq + 1))
+        try:
+            used = need(n)
+        except jax.errors.JaxRuntimeError as err:
+            if "RESOURCE_EXHAUSTED" not in str(err):
+                raise
+            tries.append((n, None))
+            n = int(n * 0.95)
+            continue
+        tries.append((n, max(used)))
+        if max(used) <= budget:
+            break
+        n = min(
+            int(lo + (n - lo) * (budget - a) / (u - a)) - 1
+            for (a, _), u in zip(lines, used)
+            if u > budget
+        )
+    else:
+        raise RuntimeError(f"no pool size fits {budget} bytes: {tries}")
+    return {"n_pages": n, "bytes_limit": limit, "param_bytes": params, "tries": tries}
+
+
+def build_engine(cfg, mesh, params, mix, n_pages: int, paged_impl=None):
+    from repro.serving import Engine
+
+    return Engine(
+        cfg, mesh, engine_cfg=engine_config(mix, n_pages), params=params,
+        paged_impl=paged_impl,
+    )
+
+
+def warm(engine, mix) -> None:
+    """Run every prefill program the mix can produce once, through
+    ``submit``/``step``, each followed by the decode step on the pool it
+    leaves (two-token requests finish in their admission step)."""
+    rng = np.random.default_rng(0)
+    buckets = prefill_buckets(mix, engine.kv.page)
+    for n, s in buckets:
+        for _ in range(n):
+            engine.submit(rng.integers(0, engine.cfg.vocab_size, size=s, dtype=np.int32), 2)
+        done = engine.step()
+        if len(done) != n or engine.scheduler.active() or engine.scheduler.waiting:
+            raise RuntimeError(f"warm-up of bucket ({n}, {s}) did not finish in one step")
+    engine.reset_stats()
+
+
+# ----------------------------------------------------------------------
+# The open loop
+# ----------------------------------------------------------------------
+
+
+@dataclasses.dataclass
+class Req:
+    idx: int
+    due: float  # absolute perf_counter seconds
+    plen: int
+    olen: int
+    uid: int = -1
+    submit: float | None = None
+    times: list = dataclasses.field(default_factory=list)  # delivery time per token
+    first_step: int | None = None  # harness step that delivered the first token
+    finished: object = None  # FinishedRequest
+    finish_time: float | None = None
+    failed: bool = False
+    served: list | None = None  # token ids served by the close (finished or not)
+
+    @property
+    def count(self) -> int:
+        return len(self.times)
+
+
+@dataclasses.dataclass
+class Step:
+    start: float
+    end: float
+    prefill_tokens: int = 0  # prompt tokens of requests admitted in the step
+    prefills: list = dataclasses.field(default_factory=list)  # prompt lengths
+    decode_positions: list = dataclasses.field(default_factory=list)
+
+
+@dataclasses.dataclass
+class Window:
+    open: float
+    close: float
+    reqs: list[Req]
+    steps: list[Step]
+    compiles: int
+    waiting_at_close: int = 0  # requests due but not admitted when the window closed
+
+    @property
+    def seconds(self) -> float:
+        return self.close - self.open
+
+
+def open_loop(engine, sched: generator.Schedule, seconds: float, *, t_open: float | None = None,
+              annotate: bool = False, hooks=None) -> Window:
+    """Submit each request when due, step while there is work, sleep
+    only while the engine is idle. Tokens are stamped at the end of the
+    step that produced them."""
+    import jax
+
+    from repro.analysis.guards import compile_events_total
+
+    span = (lambda name, **kw: jax.profiler.TraceAnnotation(name, **kw)) if annotate else (
+        lambda name, **kw: contextlib.nullcontext())
+    clock = time.perf_counter
+    t_open = clock() if t_open is None else t_open
+    close = t_open + seconds
+    reqs: list[Req] = []
+    by_uid: dict[int, Req] = {}
+    pending = [
+        Req(i, t_open + float(d), int(p), int(o))
+        for i, (d, p, o) in enumerate(zip(sched.due_s, sched.prompt_len, sched.output_len))
+    ]
+    prompts = sched.prompts
+    nxt = 0
+    steps: list[Step] = []
+    c0 = compile_events_total()
+    hooks = hooks or {}
+    while True:
+        now = clock()
+        if now >= close:
+            break
+        if "tick" in hooks:
+            hooks["tick"](now)
+        if nxt < len(pending) and pending[nxt].due <= now:
+            with span("submit"):
+                while nxt < len(pending) and pending[nxt].due <= now:
+                    r = pending[nxt]
+                    r.uid = engine.submit(prompts[nxt], r.olen)
+                    r.submit = clock()
+                    by_uid[r.uid] = r
+                    reqs.append(r)
+                    nxt += 1
+        if not engine.scheduler.active() and not engine.scheduler.waiting:
+            wake = pending[nxt].due if nxt < len(pending) else close
+            with span("wait_for_arrival"):
+                time.sleep(max(0.0, min(wake, close) - clock()))
+            continue
+        before = {st.request.uid: st.pos for st in engine.scheduler.active()}
+        t0 = clock()
+        with span("step", step=len(steps)):
+            fins = engine.step()
+        t1 = clock()
+        step = Step(t0, t1)
+        counts = {st.request.uid: len(st.generated) for st in engine.scheduler.active()}
+        for f in fins:
+            counts[f.uid] = len(f.tokens)
+            r = by_uid[f.uid]
+            r.finished, r.finish_time = f, t1
+            r.failed = f.finish_reason in ("rejected", "capacity") or f.preemptions > 0
+        for uid, n in counts.items():
+            r = by_uid[uid]
+            new = n - r.count
+            if new <= 0:
+                continue
+            if r.count == 0:
+                r.first_step = len(steps)
+                step.prefills.append(r.plen)
+                step.prefill_tokens += r.plen
+                new -= 1
+                r.times.append(t1)
+            if new > 0:
+                # decode tokens: the request's slot sat at ``pos`` before
+                # the step, or at ``plen`` when admitted in this step
+                p0 = before.get(uid, r.plen)
+                step.decode_positions.extend(range(p0, p0 + new))
+                r.times.extend([t1] * new)
+        steps.append(step)
+    compiles = compile_events_total() - c0
+    for r in reqs:
+        if r.finished is not None:
+            r.served = [int(t) for t in r.finished.tokens]
+    for st in engine.scheduler.active():
+        by_uid[st.request.uid].served = [int(t) for t in st.generated]
+    return Window(t_open, close, reqs, steps, compiles, len(engine.scheduler.waiting))
+
+
+# ----------------------------------------------------------------------
+# End-to-end metrics
+# ----------------------------------------------------------------------
+
+
+def _p(vals, q) -> float:
+    return float(np.percentile(np.asarray(vals, np.float64), q)) if len(vals) else float("nan")
+
+
+def ttft_ms(win: Window) -> list[float]:
+    """Due to first token, for every request due in the window; one
+    with no first token by the close counts at its wait so far."""
+    out = []
+    for r in win.reqs:
+        if not (win.open <= r.due < win.close):
+            continue
+        t = r.times[0] if r.times and r.times[0] <= win.close else win.close
+        out.append((t - r.due) * 1e3)
+    return out
+
+
+def itl_ms(win: Window) -> list[float]:
+    """Gaps between consecutive tokens of a request, for every token
+    delivered in the window that has one before it."""
+    out = []
+    for r in win.reqs:
+        ts = r.times
+        for a, b in zip(ts, ts[1:]):
+            if win.open <= b <= win.close:
+                out.append((b - a) * 1e3)
+    return out
+
+
+def tokens_in_window(win: Window) -> int:
+    return sum(1 for r in win.reqs for t in r.times if win.open <= t <= win.close)
+
+
+def end_to_end(win: Window, setup_s: float, names: list[str]) -> dict:
+    """The named end-to-end metrics: ``setup_s``, ``output_tokens_per_s``,
+    and ``ttft_p<q>_ms`` / ``itl_p<q>_ms`` for any percentile ``q``."""
+    out = {}
+    for name in names:
+        m = re.fullmatch(r"(ttft|itl)_p(\d+)_ms", name)
+        if m:
+            vals = ttft_ms(win) if m.group(1) == "ttft" else itl_ms(win)
+            out[name] = _p(vals, int(m.group(2)))
+        elif name == "output_tokens_per_s":
+            out[name] = tokens_in_window(win) / win.seconds
+        elif name == "setup_s":
+            out[name] = setup_s
+        else:
+            raise KeyError(f"no end-to-end metric named {name!r}")
+    return out
+
+
+# ----------------------------------------------------------------------
+# Correctness
+# ----------------------------------------------------------------------
+
+
+def pick_sample(win: Window, seed: int) -> list[Req]:
+    """Requests for the reference: among those that served tokens by the
+    close (finished, or still decoding), the one with the longest
+    context, then others in an order drawn from the seed, up to
+    ``SAMPLE_TOKENS`` served tokens."""
+    done = [r for r in win.reqs if r.served and not r.failed]
+    if not done:
+        return []
+    longest = max(done, key=lambda r: (r.plen + len(r.served), r.idx))
+    rest = [r for r in done if r is not longest]
+    order = generator.seed_rng(seed, 5).permutation(len(rest))
+    out, n = [longest], len(longest.served)
+    for i in order:
+        if n >= SAMPLE_TOKENS or len(out) >= SAMPLE_MAX_REQUESTS:
+            break
+        out.append(rest[i])
+        n += len(rest[i].served)
+    return out
+
+
+def served_gaps(ref: Reference, prompts: list, served: list, control: Reference | None = None):
+    """Per request, per served token: how far its reference logit lies
+    below the reference's best (inf for an id outside the vocabulary).
+    With ``control``, also the gaps of the tokens the control puts first
+    at the same positions."""
+    seqs = [np.concatenate([p, s[:-1]]).astype(np.int32) for p, s in zip(prompts, served)]
+    reads = [np.arange(p.size - 1, q.size) for p, q in zip(prompts, seqs)]
+    out, ctl = [], []
+    lgs = ref.logits_many(seqs, reads)
+    picks = [c.argmax(-1) for c in control.logits_many(seqs, reads)] if control else None
+    for i, (lg, s) in enumerate(zip(lgs, served)):
+        s = np.asarray(s, np.int64)
+        best = lg.max(axis=-1)
+        rows = np.arange(s.size)
+        ok = (s >= 0) & (s < lg.shape[-1])
+        got = np.where(ok, lg[rows, np.clip(s, 0, lg.shape[-1] - 1)], -np.inf)
+        out.append(best - got)
+        if picks is not None:
+            ctl.append(best - lg[rows, picks[i]])
+    return out, (ctl if control else None)
