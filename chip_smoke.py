@@ -298,12 +298,6 @@ def one_chip(clock) -> None:
             engine.paged_impl == "pallas",
             f"{name}: paged decode took {engine.paged_impl!r}, not the kernel",
         )
-        roof = engine.stats_summary()["roofline"]
-        check(roof["available"], f"{name}: roofline unavailable: {roof}")
-        log(
-            f"{name}: decode roofline bottleneck={roof['bottleneck']} "
-            f"(HLO estimate, not a measurement)"
-        )
         report(name, rep, dev)
         del engine
         gc.collect()

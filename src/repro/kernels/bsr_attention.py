@@ -157,6 +157,8 @@ def block_sparse_attention_pallas(
         ),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
+        # the kernel's name in a profiler trace
+        name="block_sparse_attention_pallas",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),

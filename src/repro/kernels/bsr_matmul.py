@@ -104,6 +104,8 @@ def bsr_matmul_pallas(
         ),
         out_shape=jax.ShapeDtypeStruct((B, nb_out * b), out_dtype),
         interpret=interpret,
+        # the kernel's name in a profiler trace
+        name="bsr_matmul_pallas",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),

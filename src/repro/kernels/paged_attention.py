@@ -165,6 +165,8 @@ def paged_decode_attention_pallas(
         ),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
+        # the kernel's name in a profiler trace
+        name="paged_decode_attention_pallas",
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),

@@ -788,24 +788,30 @@ def apply_attention(
     (B, P) maps each slot's logical pages to physical ones. ``paged_impl``
     selects the paged decode read: None/"gather" portable jnp gathers, or
     "pallas"/"interpret" for the fused page-pool kernel.
+
+    Named scopes mark the parts in a profiler trace: ``attn_proj`` (the
+    q/k/v and output projections, with qk-norm and rope), and in the
+    paged modes ``kv_write`` (the new token's write into the pool) and
+    ``paged_attention`` (the read).
     """
     c = spec.cfg
     b, s, _ = x.shape
     hk, g, d = c.num_kv_heads, c.num_heads // c.num_kv_heads, c.head_dim
     scale = d ** -0.5
 
-    q = apply_linear(spec.wq, params["wq"], x, impl=impl)
-    k = apply_linear(spec.wk, params["wk"], x, impl=impl)
-    v = apply_linear(spec.wv, params["wv"], x, impl=impl)
-    q = q.reshape(b, s, c.num_heads, d)
-    k = k.reshape(b, s, hk, d)
-    v = v.reshape(b, s, hk, d)
-    if c.qk_norm:
-        q = head_rmsnorm(params["q_norm"], q, c.norm_eps)
-        k = head_rmsnorm(params["k_norm"], k, c.norm_eps)
-    q = apply_rope(q, positions, c.rope_theta, c.mrope_sections)
-    k = apply_rope(k, positions, c.rope_theta, c.mrope_sections)
-    qg = q.reshape(b, s, hk, g, d)
+    with jax.named_scope("attn_proj"):
+        q = apply_linear(spec.wq, params["wq"], x, impl=impl)
+        k = apply_linear(spec.wk, params["wk"], x, impl=impl)
+        v = apply_linear(spec.wv, params["wv"], x, impl=impl)
+        q = q.reshape(b, s, c.num_heads, d)
+        k = k.reshape(b, s, hk, d)
+        v = v.reshape(b, s, hk, d)
+        if c.qk_norm:
+            q = head_rmsnorm(params["q_norm"], q, c.norm_eps)
+            k = head_rmsnorm(params["k_norm"], k, c.norm_eps)
+        q = apply_rope(q, positions, c.rope_theta, c.mrope_sections)
+        k = apply_rope(k, positions, c.rope_theta, c.mrope_sections)
+        qg = q.reshape(b, s, hk, g, d)
     if mode in ("train", "prefill", "prefill_prefix"):
         aspec = _attn_activation_specs(c, s)
         if aspec is not None:
@@ -864,30 +870,39 @@ def apply_attention(
             v = constrain(c, v, ba, None, None, "model")
         # write-at-position: each slot's token lands in its own page; idle
         # slots all route to the shared trash page 0 (never read back).
-        phys = jnp.take_along_axis(page_table, (pos // page)[:, None], axis=1)
-        phys = phys[:, 0]
-        off = pos % page
-        kc = cache["k"].at[phys, :, off].set(k[:, 0].astype(cache["k"].dtype))
-        vc = cache["v"].at[phys, :, off].set(v[:, 0].astype(cache["v"].dtype))
-        kc = constrain_paged_pool(c, kc)
-        vc = constrain_paged_pool(c, vc)
+        with jax.named_scope("kv_write"):
+            phys = jnp.take_along_axis(
+                page_table, (pos // page)[:, None], axis=1
+            )
+            phys = phys[:, 0]
+            off = pos % page
+            kc = cache["k"].at[phys, :, off].set(
+                k[:, 0].astype(cache["k"].dtype)
+            )
+            vc = cache["v"].at[phys, :, off].set(
+                v[:, 0].astype(cache["v"].dtype)
+            )
+            kc = constrain_paged_pool(c, kc)
+            vc = constrain_paged_pool(c, vc)
         new_cache = {"k": kc, "v": vc}
-        if mode == "decode_paged_sparse" and page == c.attn_block:
-            o = paged_sparse_decode_attention_jnp(
-                qg,
-                kc,
-                vc,
-                page_table,
-                pos,
-                sm_scale=scale,
-                local_blocks=c.attn_local_blocks,
-                global_blocks=c.attn_global_blocks,
-                impl=paged_impl,
-            )
-        else:
-            o = paged_decode_attention_jnp(
-                qg, kc, vc, page_table, pos, sm_scale=scale, impl=paged_impl
-            )
+        with jax.named_scope("paged_attention"):
+            if mode == "decode_paged_sparse" and page == c.attn_block:
+                o = paged_sparse_decode_attention_jnp(
+                    qg,
+                    kc,
+                    vc,
+                    page_table,
+                    pos,
+                    sm_scale=scale,
+                    local_blocks=c.attn_local_blocks,
+                    global_blocks=c.attn_global_blocks,
+                    impl=paged_impl,
+                )
+            else:
+                o = paged_decode_attention_jnp(
+                    qg, kc, vc, page_table, pos, sm_scale=scale,
+                    impl=paged_impl,
+                )
     elif mode in ("decode", "decode_sparse"):
         assert cache is not None and pos is not None
         kc = jax.lax.dynamic_update_slice(
@@ -950,7 +965,8 @@ def apply_attention(
         if aspec is not None:
             o = constrain(c, o, *aspec["o"])
     o = o.reshape(b, s, c.q_dim)
-    y = apply_linear(spec.wo, params["wo"], o, impl=impl)
+    with jax.named_scope("attn_proj"):
+        y = apply_linear(spec.wo, params["wo"], o, impl=impl)
     ba = c.batch_axes or None
     y = constrain(c, y, ba, *([None] * (y.ndim - 1)))
     return y, new_cache
@@ -1051,10 +1067,8 @@ def init_lm_head(key: jax.Array, cfg: ModelConfig) -> dict:
 def lm_logits(
     cfg: ModelConfig, head: dict, embed: dict, x: jax.Array
 ) -> jax.Array:
-    if cfg.tie_embeddings:
-        w = embed["tok"].T
-    else:
-        w = head["w"]
-    return jnp.einsum(
-        "...d,dv->...v", x, w, preferred_element_type=jnp.float32
-    )
+    with jax.named_scope("lm_head"):
+        w = embed["tok"].T if cfg.tie_embeddings else head["w"]
+        return jnp.einsum(
+            "...d,dv->...v", x, w, preferred_element_type=jnp.float32
+        )

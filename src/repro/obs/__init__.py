@@ -1,10 +1,12 @@
 """Observability for the serving stack: tracing spans + typed metrics.
 
-Three pieces, deliberately dependency-free (numpy only):
+Three pieces, on numpy alone (and ``jax.profiler``'s
+``TraceAnnotation``):
 
   * :mod:`repro.obs.trace` — ring-buffered, ``perf_counter_ns``-stamped
-    span tracer (``Tracer`` / ``NULL_TRACER``); the engine records
-    per-request lifecycle spans and per-step phase spans through it.
+    span tracer (``Tracer`` / ``NullTracer``); the engine records
+    per-request lifecycle spans and per-step phase spans through it,
+    and the phase spans land in a running profiler's trace as well.
   * :mod:`repro.obs.metrics` — ``MetricsRegistry`` of counters, gauges
     and raw-sample histograms; ``ServeStats`` / ``SwapStats`` /
     ``PrefixStats`` are views over one engine-owned registry.

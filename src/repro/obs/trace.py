@@ -8,8 +8,8 @@ Design constraints, in order:
      small ints up front (``Engine.__init__`` resolves every id it will
      ever use), so recording does no string work and no per-event
      allocation beyond the open-span stack push.
-  2. **Free when off.**  :data:`NULL_TRACER` implements the same surface
-     as pure no-ops, so engine code calls ``self.tracer.begin(...)``
+  2. **Free when off.**  :class:`NullTracer` implements the same surface
+     as no-ops, so engine code calls ``self.tracer.begin(...)``
      unconditionally — no ``if traced:`` branches on the hot path, and
      a disabled engine does zero obs work (tests assert this by
      patching :func:`perf_counter_ns` with a counting shim).
@@ -27,6 +27,17 @@ start is the request's submit timestamp, recorded only at admission —
 and ``C`` counter samples (a0 = the integer value; the exporter renders
 them as Perfetto counter tracks under the spans, e.g. pool occupancy
 and queue depth per step).
+
+**The profiler's clock.** A name interned with ``stats`` is a *phase
+span*: while a ``jax.profiler`` session runs, its ``begin``/``end`` also
+open and close a ``TraceAnnotation`` named ``engine.<label>``, carrying
+the end call's ``a0``/``a1`` under the ``stats`` keys. It then sits on
+the host timeline of the profiler trace, on the same clock as the
+device's operations. Both tracers do this, so a ``trace=False`` engine
+shows its phases in a profiler trace too; with no profiler running it
+costs one boolean check per call. ``X``/``C``/``I`` events stay in the
+ring only (the profiler cannot backdate a span), as do spans of names
+interned without ``stats``.
 """
 
 from __future__ import annotations
@@ -34,14 +45,46 @@ from __future__ import annotations
 from time import perf_counter_ns
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 __all__ = ["Tracer", "NullTracer", "NULL_TRACER", "KIND_B", "KIND_E",
-           "KIND_I", "KIND_X", "KIND_C"]
+           "KIND_I", "KIND_X", "KIND_C", "PROFILER_PREFIX"]
 
 KIND_B, KIND_E, KIND_I, KIND_X, KIND_C = 0, 1, 2, 3, 4
+# phase spans in a profiler trace are named PROFILER_PREFIX + label
+PROFILER_PREFIX = "engine."
+_profiling = TraceAnnotation.is_enabled
 
 
-class Tracer:
+class _PhaseSpans:
+    """Mirror of phase spans into a running profiler session, shared by
+    both tracers: ``_phases`` maps a name id to its annotation name and
+    stat keys; ``_live`` holds the annotations opened by ``begin``."""
+
+    def _init_phases(self) -> None:
+        self._phases: dict[int, tuple[str, tuple[str, ...]]] = {}
+        self._live: dict[int, TraceAnnotation] = {}
+
+    def _phase(self, nid: int, label: str, stats) -> None:
+        if stats is not None:
+            self._phases[nid] = (PROFILER_PREFIX + label, tuple(stats))
+
+    def _phase_begin(self, name: int) -> None:
+        if _profiling() and name in self._phases:
+            ann = TraceAnnotation(self._phases[name][0])
+            ann.__enter__()
+            self._live[name] = ann
+
+    def _phase_end(self, name: int, a0: int, a1: int) -> None:
+        if self._live:
+            ann = self._live.pop(name, None)
+            if ann is not None:
+                keys = self._phases[name][1]
+                ann.set_metadata(**dict(zip(keys, (int(a0), int(a1)))))
+                ann.__exit__(None, None, None)
+
+
+class Tracer(_PhaseSpans):
     enabled = True
 
     def __init__(self, capacity: int = 1 << 16):
@@ -65,6 +108,7 @@ class Tracer:
         # per-track stack of open span name-ids (B pushed, E pops)
         self._open: list[list[int]] = []
         self.truncated_spans = 0  # spans force-closed by reset()
+        self._init_phases()
 
     # -- interning -----------------------------------------------------
 
@@ -78,12 +122,15 @@ class Tracer:
             self._open.append([])
         return tid
 
-    def name(self, label: str) -> int:
+    def name(self, label: str, stats: tuple[str, ...] | None = None) -> int:
+        """Intern a span/event name -> id. ``stats`` (the keys of a span's
+        ``a0``/``a1``) makes it a phase span (module docstring)."""
         nid = self._name_ids.get(label)
         if nid is None:
             nid = len(self._name_labels)
             self._name_ids[label] = nid
             self._name_labels.append(label)
+        self._phase(nid, label, stats)
         return nid
 
     # -- recording (hot path) ------------------------------------------
@@ -104,6 +151,7 @@ class Tracer:
 
     def begin(self, track: int, name: int, a0: int = 0, a1: int = 0) -> int:
         """Open a span on ``track``; returns its start timestamp (ns)."""
+        self._phase_begin(name)
         ts = perf_counter_ns()
         self._record(KIND_B, track, name, ts, 0, a0, a1)
         self._open[track].append(name)
@@ -113,6 +161,7 @@ class Tracer:
         """Close the innermost open span on ``track``.  A no-op if the
         span was already force-closed by :meth:`reset` (so callers never
         need to remember whether a reset happened mid-span)."""
+        self._phase_end(name, a0, a1)
         stack = self._open[track]
         if not stack or stack[-1] != name:
             return
@@ -198,10 +247,12 @@ class Tracer:
         return export_perfetto({pid: self}, path)
 
 
-class NullTracer:
-    """No-op tracer bound to disabled engines.  Same surface as
-    :class:`Tracer`; every method returns immediately so hot-path call
-    sites stay branch-free and cost one attribute lookup + call."""
+class NullTracer(_PhaseSpans):
+    """Tracer bound to disabled engines.  Same surface as
+    :class:`Tracer`; it records nothing and never reads the clock, so
+    hot-path call sites stay branch-free. Only phase spans do anything,
+    and only while a profiler session runs. Each engine makes its own:
+    the open phase spans are per-instance state."""
 
     enabled = False
     truncated_spans = 0
@@ -209,17 +260,26 @@ class NullTracer:
     n_recorded = 0
     _track_labels: tuple = ()  # exporters see an empty process
 
+    def __init__(self):
+        self._init_phases()
+
     def track(self, label: str) -> int:
         return 0
 
-    def name(self, label: str) -> int:
-        return 0
+    def name(self, label: str, stats: tuple[str, ...] | None = None) -> int:
+        """0 for ring-only names; phase spans get ids from 1."""
+        if stats is None:
+            return 0
+        nid = 1 + len(self._phases)
+        self._phase(nid, label, stats)
+        return nid
 
     def begin(self, track: int, name: int, a0: int = 0, a1: int = 0) -> int:
+        self._phase_begin(name)
         return 0
 
     def end(self, track: int, name: int, a0: int = 0, a1: int = 0) -> None:
-        return None
+        self._phase_end(name, a0, a1)
 
     def instant(self, track: int, name: int, a0: int = 0, a1: int = 0) -> None:
         return None

@@ -34,8 +34,10 @@ the real prompt length — bucket padding never pins real pages.
 from __future__ import annotations
 
 import dataclasses
+import gc
 import threading
 import time
+import weakref
 
 import jax
 import jax.numpy as jnp
@@ -44,16 +46,17 @@ import numpy as np
 from repro.analysis.guards import compile_events_total, hot_path
 from repro.configs.base import ModelConfig
 from repro.obs import (
-    NULL_TRACER,
     BurnRateMonitor,
     FlightRecorder,
     MetricsRegistry,
+    NullTracer,
     SloConfig,
     SpikeDetector,
     Tracer,
     WindowedView,
 )
 from repro.obs.slo import CRITICAL
+from repro.obs.trace import PROFILER_PREFIX
 from repro.distributed import sharding
 from repro.models import transformer as T
 from repro.serving import sampling as sampling_lib
@@ -102,7 +105,11 @@ class EngineConfig:
     ``trace`` turns on span tracing (``repro.obs``): True for the
     default ring capacity, an int for an explicit event capacity. Off
     (the default), the engine binds the no-op tracer and does zero
-    tracing work.
+    tracing work. Either way, while a ``jax.profiler`` session runs the
+    step's phases (``engine.admission``, ``engine.prefill``,
+    ``engine.decode_prepare``, ``engine.decode_step``,
+    ``engine.host_sync``, ``engine.finish``, and ``engine.gc`` for a
+    garbage collection) appear in its trace.
 
     ``monitor`` turns on the live telemetry plane (``repro.obs.windows``):
     True for a 30 s rolling window, a float for an explicit window in
@@ -235,6 +242,41 @@ def _argmax_first(out):
     return (jnp.argmax(logits, axis=-1).astype(jnp.int32), *rest)
 
 
+class _GcWatch:
+    """The engine's ``gc.callbacks`` hook: adds each collection's pause
+    to the engine's ``ServeStats.gc_pause_s`` and, while a profiler
+    session runs, spans it as ``engine.gc`` (stats: generation,
+    collected). A collection stops the whole process, so every engine
+    in it counts each one. Holds the engine weakly and leaves
+    ``gc.callbacks`` when the engine is collected. The span bypasses
+    the tracer: a collection can start inside a ring write."""
+
+    def __init__(self, engine: "Engine"):
+        self._engine = weakref.ref(engine)
+        self._t0: float | None = None
+        self._span: jax.profiler.TraceAnnotation | None = None
+        gc.callbacks.append(self)
+        weakref.finalize(engine, gc.callbacks.remove, self)
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._t0 = time.perf_counter()
+            if jax.profiler.TraceAnnotation.is_enabled():
+                self._span = jax.profiler.TraceAnnotation(
+                    PROFILER_PREFIX + "gc", generation=info["generation"]
+                )
+                self._span.__enter__()
+            return
+        if self._span is not None:
+            self._span.set_metadata(collected=info["collected"])
+            self._span.__exit__(None, None, None)
+            self._span = None
+        engine = self._engine()
+        if engine is not None and self._t0 is not None:
+            engine.stats.record_gc_pause(time.perf_counter() - self._t0)
+        self._t0 = None
+
+
 class _Plan:
     """One admission pass's outcome: prefill ``groups`` keyed by
     ``(suffix bucket, prefix-page bucket)``, swapped sequences to
@@ -344,17 +386,20 @@ class Engine:
             # warmed at init so neither compiles mid-traffic. Presence
             # rides as its own (donatable) arg; the small (slots,) param
             # arrays are device-cached between admissions.
-            self._decode = jax.jit(
-                lambda p, c, t, pos, pt: _argmax_first(
+            # The programs are named functions, not lambdas: their names
+            # are the XLA modules' names in a profiler trace
+            # (jit_engine_decode, ...), which is how a trace reduction
+            # tells the decode program from the prefill ones.
+            def engine_decode(p, c, t, pos, pt):
+                return _argmax_first(
                     T.decode_step_paged(
                         cfg, p, c, t, pos, pt, impl=impl,
                         paged_impl=paged_impl,
                     )
-                ),
-                donate_argnums=(1,),
-            )
-            self._decode_sampled = jax.jit(
-                lambda p, c, t, pos, pt, samp, pres: T.decode_step_paged(
+                )
+
+            def engine_decode_sampled(p, c, t, pos, pt, samp, pres):
+                return T.decode_step_paged(
                     cfg, p, c, t, pos, pt, impl=impl, paged_impl=paged_impl,
                     sampler={
                         **samp,
@@ -365,23 +410,47 @@ class Engine:
                         "presence": pres,
                     },
                     sampler_candidates=ecfg.sampler_candidates,
-                ),
-                donate_argnums=(1, 6),
-            )
-            # one wrapper; jax.jit specializes per (N, S) bucket shape
-            self._prefill = jax.jit(
-                lambda p, t, plens, c, rows: _argmax_first(
+                )
+
+            def engine_prefill(p, t, plens, c, rows):
+                return _argmax_first(
                     T.prefill_paged(cfg, p, t, plens, c, rows, impl=impl)
-                ),
-                donate_argnums=(3,),
-            )
-            self._prefill_sampled = jax.jit(
-                lambda p, t, plens, c, rows, samp, pres: T.prefill_paged(
+                )
+
+            def engine_prefill_sampled(p, t, plens, c, rows, samp, pres):
+                return T.prefill_paged(
                     cfg, p, t, plens, c, rows, impl=impl,
                     sampler={**samp, "presence": pres},
                     sampler_candidates=ecfg.sampler_candidates,
-                ),
-                donate_argnums=(3, 6),
+                )
+
+            def engine_prefill_pre(p, t, plens, c, rows, prow, plen_pre):
+                return _argmax_first(
+                    T.prefill_paged(
+                        cfg, p, t, plens, c, rows, impl=impl,
+                        prefix_rows=prow, prefix_lens=plen_pre,
+                    )
+                )
+
+            def engine_prefill_pre_sampled(
+                p, t, plens, c, rows, prow, plen_pre, ft, fl, samp, pres
+            ):
+                return T.prefill_paged(
+                    cfg, p, t, plens, c, rows, impl=impl,
+                    prefix_rows=prow, prefix_lens=plen_pre,
+                    full_tokens=ft, full_plens=fl,
+                    sampler={**samp, "presence": pres},
+                    sampler_candidates=ecfg.sampler_candidates,
+                )
+
+            self._decode = jax.jit(engine_decode, donate_argnums=(1,))
+            self._decode_sampled = jax.jit(
+                engine_decode_sampled, donate_argnums=(1, 6)
+            )
+            # one wrapper; jax.jit specializes per (N, S) bucket shape
+            self._prefill = jax.jit(engine_prefill, donate_argnums=(3,))
+            self._prefill_sampled = jax.jit(
+                engine_prefill_sampled, donate_argnums=(3, 6)
             )
             # cache-aware partial-prefill variants: tokens/plens carry
             # only the uncached suffix, (pre_rows, pre_lens) map the
@@ -389,25 +458,10 @@ class Engine:
             # P_prefix) bucket; miss-only groups take the plain
             # variants above, so cache-off traffic compiles nothing new.
             self._prefill_pre = jax.jit(
-                lambda p, t, plens, c, rows, prow, plen_pre: _argmax_first(
-                    T.prefill_paged(
-                        cfg, p, t, plens, c, rows, impl=impl,
-                        prefix_rows=prow, prefix_lens=plen_pre,
-                    )
-                ),
-                donate_argnums=(3,),
+                engine_prefill_pre, donate_argnums=(3,)
             )
             self._prefill_pre_sampled = jax.jit(
-                lambda p, t, plens, c, rows, prow, plen_pre, ft, fl, samp, pres: (
-                    T.prefill_paged(
-                        cfg, p, t, plens, c, rows, impl=impl,
-                        prefix_rows=prow, prefix_lens=plen_pre,
-                        full_tokens=ft, full_plens=fl,
-                        sampler={**samp, "presence": pres},
-                        sampler_candidates=ecfg.sampler_candidates,
-                    )
-                ),
-                donate_argnums=(3, 10),
+                engine_prefill_pre_sampled, donate_argnums=(3, 10)
             )
             # presence rebuild for a *resumed* fancy sequence: one jit'd
             # scatter of its prompt+generated tokens (padded with the
@@ -453,13 +507,14 @@ class Engine:
                 capacity=(1 << 16) if ecfg.trace is True else int(ecfg.trace)
             )
         else:
-            self.tracer = NULL_TRACER
+            self.tracer = NullTracer()
         self._intern_trace_ids()
         self.scheduler = Scheduler(
             ecfg.max_slots,
             on_event=self._sched_event if self.tracer.enabled else None,
         )
         self.stats = ServeStats(self.metrics)
+        _GcWatch(self)
         # radix-tree prefix cache: parked pages reuse free pool space
         # opportunistically and are evicted (LRU) the moment the
         # allocator wants them back — admission is never blocked
@@ -536,7 +591,6 @@ class Engine:
         # (zero-compile) step: warmup-adjacent compiles — fresh prefill
         # buckets, the sampled variants — are expected, not incidents
         self._flight_armed = False
-        self._roofline: dict | None = None
         # backend allocator introspection, probed once: platforms
         # without memory_stats (CPU) silently report 0 bytes
         self._device_memory_stats = None
@@ -553,7 +607,9 @@ class Engine:
     def _intern_trace_ids(self) -> None:
         """Resolve every track/name id the engine will ever record —
         hot-path tracer calls then do no string work at all. (The
-        NULL tracer returns 0 for everything; the ids are never used.)"""
+        NullTracer returns 0 for every ring-only name.) The phase
+        spans name the stats their end call's a0/a1 carry into a
+        profiler trace."""
         tr = self.tracer
         self._tk_admission = tr.track("engine:admission")
         self._tk_prefill = tr.track("engine:prefill")
@@ -563,10 +619,12 @@ class Engine:
         self._tk_slot = [
             tr.track(f"slot{i}") for i in range(self.ecfg.max_slots)
         ]
-        self._nm_admission = tr.name("admission")
-        self._nm_prefill = tr.name("prefill")
-        self._nm_decode_step = tr.name("decode_step")
-        self._nm_host_sync = tr.name("host_sync")
+        self._nm_admission = tr.name("admission", ("admitted", "compiles"))
+        self._nm_prefill = tr.name("prefill", ("s", "n"))
+        self._nm_decode_prepare = tr.name("decode_prepare", ("active",))
+        self._nm_decode_step = tr.name("decode_step", ("active", "compiles"))
+        self._nm_host_sync = tr.name("host_sync", ("active",))
+        self._nm_finish = tr.name("finish", ("finished",))
         self._nm_queued = tr.name("queued")
         self._nm_decode = tr.name("decode")
         self._nm_finished = tr.name("finished")
@@ -576,7 +634,6 @@ class Engine:
         self._nm_preempt = tr.name("preempt")
         self._nm_cow = tr.name("cow_split")
         self._nm_prefix_match = tr.name("prefix_match")
-        self._nm_roofline = tr.name("roofline")
         # counter lanes (Perfetto "C" samples, one set per step): pool
         # occupancy, queue depth, running slots render as counter tracks
         # under the span lanes
@@ -1298,6 +1355,7 @@ class Engine:
         active = self.scheduler.active()
         decode_dt: float | None = None
         if active:
+            tr.begin(self._tk_decode, self._nm_decode_prepare)
             tokens = np.zeros((self.ecfg.max_slots,), np.int32)
             positions = np.zeros((self.ecfg.max_slots,), np.int32)
             for st_ in active:
@@ -1306,31 +1364,33 @@ class Engine:
                 tokens[st_.slot] = st_.generated[-1]
                 positions[st_.slot] = st_.pos
             t0 = time.perf_counter()
+            with self.mesh:
+                inputs = (
+                    jnp.asarray(tokens),
+                    jnp.asarray(positions),
+                    self.kv.device_table(),
+                )
+                samp = self._decode_sampler() if self._fancy_slots else None
+            tr.end(self._tk_decode, self._nm_decode_prepare, len(active))
             tr.begin(self._tk_decode, self._nm_decode_step, len(active))
             with self.mesh:
                 # token picked inside the jit'd step either way: the one
                 # host sync fetches (slots,) ids. All-plain traffic takes
                 # the argmax variant (zero sampling overhead); any fancy
                 # active slot switches the step to the fused sampler.
-                if self._fancy_slots:
+                if samp is not None:
                     toks_dev, self.kv.buffers, self._presence = (
                         self._decode_sampled(
                             self.params,
                             self.kv.buffers,
-                            jnp.asarray(tokens),
-                            jnp.asarray(positions),
-                            self.kv.device_table(),
-                            self._decode_sampler(),
+                            *inputs,
+                            samp,
                             self._presence,
                         )
                     )
                 else:
                     toks_dev, self.kv.buffers = self._decode(
-                        self.params,
-                        self.kv.buffers,
-                        jnp.asarray(tokens),
-                        jnp.asarray(positions),
-                        self.kv.device_table(),
+                        self.params, self.kv.buffers, *inputs
                     )
                 # THE one sanctioned host sync per decode step: a single
                 # batched (slots,) fetch of every active slot's next
@@ -1350,13 +1410,14 @@ class Engine:
             self.stats.record_decode_step(
                 len(active), self.ecfg.max_slots, dt
             )
-            for st_ in active:
-                st_.pos += 1
-                st_.generated.append(int(nxt[st_.slot]))
-                if st_.done:
-                    finished.append(self._finish(st_))
-                elif st_.pos >= self.ecfg.max_len:
-                    finished.append(self._finish(st_, reason="capacity"))
+        tr.begin(self._tk_decode, self._nm_finish)
+        for st_ in active:  # empty unless the decode step ran
+            st_.pos += 1
+            st_.generated.append(int(nxt[st_.slot]))
+            if st_.done:
+                finished.append(self._finish(st_))
+            elif st_.pos >= self.ecfg.max_len:
+                finished.append(self._finish(st_, reason="capacity"))
         # the decode step has been overlapping any in-flight swap-out
         # transfers; land them on the host and drop the device staging
         for record in self._pending_swaps:
@@ -1366,6 +1427,7 @@ class Engine:
         self.stats.record_step_compiles(step_compiles)
         self._step_idx += 1
         self._observe_step(decode_dt, len(active), step_compiles)
+        tr.end(self._tk_decode, self._nm_finish, len(finished))
         return finished
 
     # ---- live telemetry (end of step, host-side) ---------------------
@@ -1378,7 +1440,7 @@ class Engine:
         """End-of-step observability hook — after the step's one
         sanctioned sync, never inside a jit'd program. With tracing,
         monitoring and the flight recorder all off this is three no-op
-        tracer calls and an early return (the NULL tracer makes no
+        tracer calls and an early return (the NullTracer makes no
         clock calls — the zero-obs-work invariant the tests assert)."""
         tr = self.tracer
         kv = self.kv
@@ -1597,80 +1659,6 @@ class Engine:
             return {"enabled": False}
         return {"enabled": True, **self._slo_mon.last}
 
-    def roofline(self) -> dict:
-        """Roofline terms for the compiled decode step (lazy, cached
-        per engine): FLOPs and HBM bytes parsed from the optimized HLO
-        (``repro.analysis.roofline``), per-device time lower bounds and
-        the dominant bottleneck, against the peaks of this engine's
-        ``device_kind``. Costs one extra AOT compile of the decode
-        program on first call. A device without an entry in
-        ``roofline.PEAKS`` reports ``available: False`` with the reason;
-        any other failure raises."""
-        if self._roofline is not None:
-            return self._roofline
-        from repro.analysis.roofline import (
-            analyze_hlo,
-            peaks_for,
-            roofline_terms,
-        )
-
-        kind = np.asarray(self.mesh.devices).flat[0].device_kind
-        try:
-            hw = peaks_for(kind)
-        except KeyError as e:
-            self._roofline = {
-                "available": False,
-                "reason": str(e.args[0]),
-                "device_kind": kind,
-                "flops": 0.0,
-                "bytes_accessed": 0.0,
-                "collective_bytes": 0.0,
-                "arithmetic_intensity": 0.0,
-                "bottleneck": "unknown",
-                "compute_s": 0.0,
-                "memory_s": 0.0,
-                "collective_s": 0.0,
-            }
-            return self._roofline
-        zeros = jnp.zeros((self.ecfg.max_slots,), jnp.int32)
-        table0 = jnp.zeros_like(jnp.asarray(self.kv.page_table))
-        with self.mesh:
-            txt = (
-                self._decode.lower(
-                    self.params, self.kv.buffers, zeros, zeros, table0
-                )
-                .compile()
-                .as_text()
-            )
-        cost = analyze_hlo(txt)
-        terms = roofline_terms(cost, hw)
-        self._roofline = {
-            "available": True,
-            "device_kind": kind,
-            "flops": cost.flops,
-            "bytes_accessed": cost.bytes_accessed,
-            "collective_bytes": cost.total_collective_bytes,
-            "arithmetic_intensity": round(
-                cost.flops / cost.bytes_accessed, 4
-            )
-            if cost.bytes_accessed
-            else 0.0,
-            "bottleneck": terms["bottleneck"],
-            "compute_s": terms["compute_s"],
-            "memory_s": terms["memory_s"],
-            "collective_s": terms["collective_s"],
-        }
-        # annotate the trace: a0 = intensity x1000, a1 = bottleneck
-        self.tracer.instant(
-            self._tk_decode,
-            self._nm_roofline,
-            int(self._roofline["arithmetic_intensity"] * 1000),
-            {"compute": 0, "memory": 1, "collective": 2}.get(
-                terms["bottleneck"], 3
-            ),
-        )
-        return self._roofline
-
     def _finish(
         self, state: SequenceState, *, reason: str | None = None
     ) -> FinishedRequest:
@@ -1798,7 +1786,6 @@ class Engine:
             out["prefix_cache"]["cached_pages"] = self.kv.cached_pages
             # keep the prom gauge in step with the pool
             self._prefix.stats.set_cached_pages(self.kv.cached_pages)
-        out["roofline"] = self.roofline()
         return out
 
     def export_perfetto(self, path: str) -> int:

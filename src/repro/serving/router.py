@@ -169,9 +169,6 @@ class ReplicaRouter:
                 for eng in self.engines
                 if eng._prefix is not None
             )
-        # every replica compiles the same decode program; engine 0's
-        # roofline stands for the fleet (per_replica carries the rest)
-        out["roofline"] = self.engines[0].roofline()
         out["per_replica"] = [eng.stats_summary() for eng in self.engines]
         return out
 

@@ -159,6 +159,12 @@ class ServeStats:
             "repro_serve_host_syncs_total",
             "sanctioned explicit device->host syncs",
         )
+        # host stalls inside the program: Python's garbage collector
+        # pauses every thread, the serving loop included
+        self._gc_pause = c(
+            "repro_serve_gc_pause_seconds_total",
+            "wall seconds in garbage collections",
+        )
         # device-memory telemetry, sampled host-side at end of step
         # (never inside a jit'd function): paged-pool occupancy and
         # fragmentation, COW reserve, host-swap residency, and the
@@ -305,6 +311,10 @@ class ServeStats:
     def slo_met(self) -> int:
         return self._slo_met.value
 
+    @property
+    def gc_pause_s(self) -> float:
+        return self._gc_pause.value
+
     # ---- recording ---------------------------------------------------
     def record_prefill(
         self,
@@ -384,6 +394,9 @@ class ServeStats:
         """Backend compiles observed while an engine step ran (should
         stay 0 after warmup — the DispatchGuard invariant)."""
         self._step_compiles.inc(n)
+
+    def record_gc_pause(self, seconds: float) -> None:
+        self._gc_pause.inc(seconds)
 
     def record_host_sync(self, n: int = 1) -> None:
         """A sanctioned explicit device->host sync (batched

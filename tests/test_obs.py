@@ -367,13 +367,7 @@ def test_stats_summary_golden_keys():
         "mean_occupancy",
         "min_occupancy",
         "max_occupancy",
-        "roofline",
     ]
-    assert set(s["roofline"]) >= {
-        "available",
-        "arithmetic_intensity",
-        "bottleneck",
-    }
     assert set(s["prefix_cache"]) == {
         "enabled",
         "lookups",
