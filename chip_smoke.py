@@ -180,8 +180,10 @@ def check_kernels(cfg) -> None:
     def bf16(shape):
         return jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
 
-    k, v = bf16((n_pages, hk, page, d)), bf16((n_pages, hk, page, d))
-    k, v = k.at[0].set(1e4), v.at[0].set(-1e4)  # poisoned trash page
+    # pools stacked over two layers, as the layer scan passes them; the
+    # reads below take layer 1
+    k, v = bf16((2, n_pages, hk, page, d)), bf16((2, n_pages, hk, page, d))
+    k, v = k.at[:, 0].set(1e4), v.at[:, 0].set(-1e4)  # poisoned trash page
     q = bf16((b, 1, hk, g, d))
     table = rng.permutation(np.arange(1, n_pages))[: b * pps].reshape(b, pps)
     table[1] = 0  # idle slot: all-trash row at position 0
@@ -212,8 +214,8 @@ def check_kernels(cfg) -> None:
     ):
         compare(
             name,
-            fn(q, k, v, table, pos, impl="pallas", **extra),
-            fn(f32(q), f32(k), f32(v), table, pos, impl="gather", **extra),
+            fn(q, k, v, 1, table, pos, impl="pallas", **extra),
+            fn(f32(q), f32(k), f32(v), 1, table, pos, impl="gather", **extra),
         )
 
     spec = L.AttnSpec(cfg).wq

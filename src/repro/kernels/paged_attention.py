@@ -19,11 +19,14 @@ Layout and masking:
   - q is pre-grouped ``(B, Hk, G, D)`` (GQA: G query heads share one
     kv head); each grid step contracts the (G, D) query block with one
     (page, D) pool page.
-  - the pools are head-major ``(n_pages, Hk, page, D)``, so one grid
-    step's K/V block ``(1, 1, page, D)`` is a whole ``(page, D)`` tile.
-    A page-major pool ``(n_pages, page, Hk, D)`` would need a size-1
-    block on its Hk axis, which the TPU compiler refuses (the last two
-    block dims must be multiples of (8, 128) or the full array dims).
+  - the pools are the model's stacked, head-major ``(L, n_pages, Hk,
+    page, D)`` buffers, read in place: the layer index is
+    scalar-prefetched with the schedule, so one grid step's K/V block
+    is the whole ``(page, D)`` tile at ``(layer, phys, h)`` and the
+    layer scan never slices a per-layer pool out for the kernel. A
+    page-major pool ``(..., page, Hk, D)`` would need a size-1 block on
+    its Hk axis, which the TPU compiler refuses (the last two block dims
+    must be multiples of (8, 128) or the full array dims).
   - ``logical`` carries the *logical* block id of every schedule slot:
     key position ``logical * page + offset`` is masked against the
     slot's current position, which also neutralizes the shared trash
@@ -49,6 +52,7 @@ _NEG_INF = float(jnp.finfo(jnp.float32).min)
 
 
 def _kernel(
+    layer_ref,
     phys_ref,
     logical_ref,
     keep_ref,
@@ -113,6 +117,7 @@ def paged_decode_attention_pallas(
     q: jax.Array,
     k_pages: jax.Array,
     v_pages: jax.Array,
+    layer: jax.Array,
     phys: jax.Array,
     logical: jax.Array,
     keep: jax.Array,
@@ -121,14 +126,16 @@ def paged_decode_attention_pallas(
     sm_scale: float,
     interpret: bool = False,
 ) -> jax.Array:
-    """q: (B, Hk, G, D). k_pages, v_pages: (n_pages, Hk, page, D) pools.
+    """q: (B, Hk, G, D). k_pages, v_pages: (L, n_pages, Hk, page, D)
+    stacked pools; layer: int32 scalar, the layer they are read at (a
+    caller with one layer's pool passes ``pool[None]`` and 0).
 
     phys/logical/keep: (B, w) int32 — physical page id, logical block id
     and keep flag per schedule slot; pos: (B,) int32 current token
     position per slot. Returns (B, Hk, G, D) in q's dtype.
     """
     b, hk, g, d = q.shape
-    _, hk_p, page, d_p = k_pages.shape
+    _, _, hk_p, page, d_p = k_pages.shape
     if (hk_p, d_p) != (hk, d):
         raise ValueError("pool head/dim mismatch with q")
     if phys.shape != logical.shape or phys.shape != keep.shape:
@@ -137,24 +144,24 @@ def paged_decode_attention_pallas(
 
     grid = (b, hk, w)
 
-    def q_map(bi, hi, t, phys_ref, logical_ref, keep_ref, pos_ref):
-        del t, phys_ref, logical_ref, keep_ref, pos_ref
+    def q_map(bi, hi, t, layer_ref, phys_ref, logical_ref, keep_ref, pos_ref):
+        del t, layer_ref, phys_ref, logical_ref, keep_ref, pos_ref
         return (bi, hi, 0, 0)
 
-    def kv_map(bi, hi, t, phys_ref, logical_ref, keep_ref, pos_ref):
+    def kv_map(bi, hi, t, layer_ref, phys_ref, logical_ref, keep_ref, pos_ref):
         del logical_ref, keep_ref, pos_ref
-        return (phys_ref[bi, t], hi, 0, 0)
+        return (layer_ref[0], phys_ref[bi, t], hi, 0, 0)
 
     kernel = functools.partial(_kernel, w=w, page=page, sm_scale=sm_scale)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=5,
             grid=grid,
             in_specs=[
                 pl.BlockSpec((1, 1, g, d), q_map),
-                pl.BlockSpec((1, 1, page, d), kv_map),
-                pl.BlockSpec((1, 1, page, d), kv_map),
+                pl.BlockSpec((pl.squeezed, 1, 1, page, d), kv_map),
+                pl.BlockSpec((pl.squeezed, 1, 1, page, d), kv_map),
             ],
             out_specs=pl.BlockSpec((1, 1, g, d), q_map),
             scratch_shapes=[
@@ -171,6 +178,7 @@ def paged_decode_attention_pallas(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
     )(
+        jnp.asarray(layer, jnp.int32).reshape(1),
         phys.astype(jnp.int32),
         logical.astype(jnp.int32),
         keep.astype(jnp.int32),

@@ -564,21 +564,51 @@ def paged_sparse_schedule(
     return idx, phys, keep
 
 
-def gather_pages(pool: jax.Array, rows: jax.Array) -> jax.Array:
-    """Read pages ``rows`` (B, n) out of a head-major pool (n_pages, Hk,
-    page, D) into a contiguous (B, n * page, Hk, D) key/value sequence
-    in logical order — the portable read the Pallas kernel is checked
-    against."""
+def gather_pages(pool: jax.Array, layer, rows: jax.Array) -> jax.Array:
+    """Read pages ``rows`` (B, n) of layer ``layer`` out of a stacked
+    head-major pool (L, n_pages, Hk, page, D) into a contiguous
+    (B, n * page, Hk, D) key/value sequence in logical order — the
+    portable read the Pallas kernel is checked against. The layer is
+    indexed inside the one gather, so no per-layer pool is sliced out."""
     b, n = rows.shape
-    _, hk, page, d = pool.shape
-    g = jnp.take(pool, rows, axis=0)  # (B, n, Hk, page, D)
+    _, _, hk, page, d = pool.shape
+    g = pool[layer, rows]  # (B, n, Hk, page, D)
     return g.swapaxes(2, 3).reshape(b, n * page, hk, d)
+
+
+def write_paged_token(
+    pool: jax.Array,
+    layer,
+    phys: jax.Array,
+    off: jax.Array,
+    new: jax.Array,
+) -> jax.Array:
+    """Write each slot's new token row ``new`` (B, Hk, D) into row
+    ``off`` (B,) of page ``phys`` (B,) at layer ``layer`` of a stacked
+    head-major pool (L, n_pages, Hk, page, D); returns the pool.
+
+    Each slot's token lands in its own page (the engine's copy-on-write
+    guard makes the slot that page's only writer); idle slots all route
+    to the shared trash page 0 (never read back), where last-write-wins
+    is harmless. Each slot's whole (Hk, page, D) page is read, its row
+    replaced and the page written back: whole tiles keep the pool in its
+    resting layout, so a donated pool is updated in place — a scatter of
+    single rows makes the compiler relayout the whole pool around it."""
+    page = pool.shape[3]
+    row = jax.lax.broadcasted_iota(jnp.int32, (1, 1, page, 1), 2)
+    pages = jnp.where(
+        row == off[:, None, None, None],
+        new[:, :, None].astype(pool.dtype),
+        pool[layer, phys],
+    )
+    return pool.at[layer, phys].set(pages)
 
 
 def _paged_attention_kernel(
     q: jax.Array,
     k_pages: jax.Array,
     v_pages: jax.Array,
+    layer,
     logical: jax.Array,
     phys: jax.Array,
     keep: jax.Array,
@@ -594,6 +624,7 @@ def _paged_attention_kernel(
         q[:, 0],
         k_pages,
         v_pages,
+        layer,
         phys,
         logical,
         keep,
@@ -608,6 +639,7 @@ def paged_decode_attention_jnp(
     q: jax.Array,
     k_pages: jax.Array,
     v_pages: jax.Array,
+    layer,
     page_table: jax.Array,
     pos: jax.Array,
     *,
@@ -616,10 +648,12 @@ def paged_decode_attention_jnp(
 ) -> jax.Array:
     """Decode against a block-paged KV cache (dense over logical pages).
 
-    q (B,1,Hk,G,D); k_pages, v_pages (n_pages, Hk, page, D); page_table
-    (B, P) int32 physical page per logical page; pos (B,) int32 position of
-    the *current* token per slot. Unallocated table entries point at the
-    shared trash page 0 — their keys land beyond ``pos`` and are masked.
+    q (B,1,Hk,G,D); k_pages, v_pages (L, n_pages, Hk, page, D) stacked
+    pools, read at layer ``layer`` (int32 scalar; one layer's pool is
+    ``pool[None]`` at 0); page_table (B, P) int32 physical page per
+    logical page; pos (B,) int32 position of the *current* token per
+    slot. Unallocated table entries point at the shared trash page 0 —
+    their keys land beyond ``pos`` and are masked.
 
     ``impl``: None/"gather" -> portable jnp gathers (the reference
     oracle); "pallas"/"interpret" -> the fused Pallas kernel reading the
@@ -628,7 +662,7 @@ def paged_decode_attention_jnp(
     if impl not in (None, "gather", "pallas", "interpret"):
         raise ValueError(f"unknown impl {impl!r}")
     b = q.shape[0]
-    page = k_pages.shape[2]
+    page = k_pages.shape[3]
     np_ = page_table.shape[1]
     if impl in ("pallas", "interpret"):
         logical = jnp.broadcast_to(
@@ -636,11 +670,11 @@ def paged_decode_attention_jnp(
         )
         keep = jnp.ones((b, np_), jnp.int32)
         return _paged_attention_kernel(
-            q, k_pages, v_pages, logical, page_table, keep, pos,
+            q, k_pages, v_pages, layer, logical, page_table, keep, pos,
             sm_scale=sm_scale, impl=impl,
         )
-    kg = gather_pages(k_pages, page_table)
-    vg = gather_pages(v_pages, page_table)
+    kg = gather_pages(k_pages, layer, page_table)
+    vg = gather_pages(v_pages, layer, page_table)
     s = _grouped_logits(q, kg) * sm_scale  # (B,Hk,G,1,S)
     ok = jnp.arange(np_ * page)[None, :] <= pos[:, None]  # logical order
     s = jnp.where(ok[:, None, None, None, :], s, -jnp.inf)
@@ -652,6 +686,7 @@ def paged_sparse_decode_attention_jnp(
     q: jax.Array,
     k_pages: jax.Array,
     v_pages: jax.Array,
+    layer,
     page_table: jax.Array,
     pos: jax.Array,
     *,
@@ -670,18 +705,18 @@ def paged_sparse_decode_attention_jnp(
     if impl not in (None, "gather", "pallas", "interpret"):
         raise ValueError(f"unknown impl {impl!r}")
     b = q.shape[0]
-    page = k_pages.shape[2]
+    page = k_pages.shape[3]
     idx, phys, keep = paged_sparse_schedule(
         page_table, pos, page,
         local_blocks=local_blocks, global_blocks=global_blocks,
     )
     if impl in ("pallas", "interpret"):
         return _paged_attention_kernel(
-            q, k_pages, v_pages, idx, phys, keep, pos,
+            q, k_pages, v_pages, layer, idx, phys, keep, pos,
             sm_scale=sm_scale, impl=impl,
         )
-    kg = gather_pages(k_pages, phys)
-    vg = gather_pages(v_pages, phys)
+    kg = gather_pages(k_pages, layer, phys)
+    vg = gather_pages(v_pages, layer, phys)
     s = _grouped_logits(q, kg) * sm_scale
     kpos = (
         idx[:, :, None] * page + jnp.arange(page)[None, None, :]
@@ -780,12 +815,17 @@ def apply_attention(
     page_table: jax.Array | None = None,
     impl: str | None = None,
     paged_impl: str | None = None,
+    layer: jax.Array | None = None,
 ):
     """Returns (y, new_cache). x: (B, S, D) [S=1 for decode].
 
-    Paged modes: ``cache`` holds slot-shared page pools ``k``/``v`` of shape
-    (n_pages, Hk, page, D), ``pos`` is per-slot (B,), and ``page_table``
-    (B, P) maps each slot's logical pages to physical ones. ``paged_impl``
+    Paged decode modes: ``cache`` holds the group's stacked slot-shared
+    page pools ``k``/``v`` (L, n_pages, Hk, page, D) and ``layer`` (int32
+    scalar) the layer this block is; the token is written and the pages
+    read at that layer, in place, and the updated stacked pools are
+    returned. ``prefill_prefix`` reads one layer's pools (n_pages, Hk,
+    page, D). In both, ``pos`` is per-slot (B,) and ``page_table`` (B, P)
+    maps each slot's logical pages to physical ones. ``paged_impl``
     selects the paged decode read: None/"gather" portable jnp gathers, or
     "pallas"/"interpret" for the fused page-pool kernel.
 
@@ -829,8 +869,8 @@ def apply_attention(
         # suffix K/V is returned for the caller's page scatter, the
         # shared prefix pages are read-only.
         assert cache is not None and pos is not None and page_table is not None
-        kp = gather_pages(cache["k"], page_table)
-        vp = gather_pages(cache["v"], page_table)
+        kp = gather_pages(cache["k"][None], 0, page_table)
+        vp = gather_pages(cache["v"][None], 0, page_table)
         block_cfg = (
             (
                 c.attn_block,
@@ -853,7 +893,8 @@ def apply_attention(
             o = constrain(c, o, *aspec["o"])
     elif mode in ("decode_paged", "decode_paged_sparse"):
         assert cache is not None and pos is not None and page_table is not None
-        page = cache["k"].shape[2]
+        assert layer is not None
+        page = cache["k"].shape[3]
         # tensor-parallel decode: head-partition the fresh K/V and the
         # grouped queries on the same axis the pool shards, so the write
         # scatter and the attention read stay shard-local (the only
@@ -868,22 +909,18 @@ def apply_attention(
             qg = constrain(c, qg, ba, None, None, None, "model")
             k = constrain(c, k, ba, None, None, "model")
             v = constrain(c, v, ba, None, None, "model")
-        # write-at-position: each slot's token lands in its own page; idle
-        # slots all route to the shared trash page 0 (never read back).
         with jax.named_scope("kv_write"):
             phys = jnp.take_along_axis(
                 page_table, (pos // page)[:, None], axis=1
             )
             phys = phys[:, 0]
             off = pos % page
-            kc = cache["k"].at[phys, :, off].set(
-                k[:, 0].astype(cache["k"].dtype)
+            kc = constrain_paged_pool(
+                c, write_paged_token(cache["k"], layer, phys, off, k[:, 0])
             )
-            vc = cache["v"].at[phys, :, off].set(
-                v[:, 0].astype(cache["v"].dtype)
+            vc = constrain_paged_pool(
+                c, write_paged_token(cache["v"], layer, phys, off, v[:, 0])
             )
-            kc = constrain_paged_pool(c, kc)
-            vc = constrain_paged_pool(c, vc)
         new_cache = {"k": kc, "v": vc}
         with jax.named_scope("paged_attention"):
             if mode == "decode_paged_sparse" and page == c.attn_block:
@@ -891,6 +928,7 @@ def apply_attention(
                     qg,
                     kc,
                     vc,
+                    layer,
                     page_table,
                     pos,
                     sm_scale=scale,
@@ -900,7 +938,7 @@ def apply_attention(
                 )
             else:
                 o = paged_decode_attention_jnp(
-                    qg, kc, vc, page_table, pos, sm_scale=scale,
+                    qg, kc, vc, layer, page_table, pos, sm_scale=scale,
                     impl=paged_impl,
                 )
     elif mode in ("decode", "decode_sparse"):

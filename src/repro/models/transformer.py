@@ -108,10 +108,13 @@ def _apply_block(
     impl: str | None,
     page_table: jax.Array | None = None,
     paged_impl: str | None = None,
+    layer: jax.Array | None = None,
 ):
     """Returns (x, new_cache, lb_loss). ``cache`` may be a zero-size
     placeholder array (cache-less scan); it is normalized to None here and a
-    placeholder is returned when the block produces no cache.
+    placeholder is returned when the block produces no cache. In paged
+    decode ``cache`` is the group's stacked pools and ``layer`` the block's
+    index into them.
 
     The block's parts run under named scopes (``norm``, ``mlp``, and
     inside ``apply_attention`` ``attn_proj``, ``kv_write`` and
@@ -162,6 +165,7 @@ def _apply_block(
         page_table=page_table,
         impl=impl,
         paged_impl=paged_impl,
+        layer=layer,
     )
     x = x + y
     with jax.named_scope("norm"):
@@ -305,6 +309,18 @@ def _run_group(
     paged_impl=None,
 ):
     """Scan ``g.count`` blocks. Returns (x, new_cache, lb_sum)."""
+    if g.shared:
+        # one param set reused; caches still stacked per occurrence
+        stacked_p = jax.tree.map(
+            lambda a: jnp.broadcast_to(a[None], (g.count,) + a.shape), gparams
+        )
+    else:
+        stacked_p = gparams
+    if mode == "decode_paged":
+        return _run_group_paged_decode(
+            cfg, g, stacked_p, x, positions, pools=cache, pos=pos,
+            impl=impl, page_table=page_table, paged_impl=paged_impl,
+        )
 
     def body(carry, xs):
         xc, lb_sum = carry
@@ -315,14 +331,6 @@ def _run_group(
             page_table=page_table, paged_impl=paged_impl,
         )
         return (xc, lb_sum + lb), c_out
-
-    if g.shared:
-        # one param set reused; caches still stacked per occurrence
-        stacked_p = jax.tree.map(
-            lambda a: jnp.broadcast_to(a[None], (g.count,) + a.shape), gparams
-        )
-    else:
-        stacked_p = gparams
 
     body_fn = body
     if cfg.remat and mode == "train":
@@ -335,8 +343,8 @@ def _run_group(
 
     n1, n2 = _remat_factors(g.count) if (cfg.remat and mode == "train") else (0, 0)
     # the scan's own operations (slicing each layer's inputs and cache
-    # out of the stacked ones, writing the cache back) carry this scope
-    # and no block scope in a profiler trace
+    # out of the stacked ones, stacking the layers' fresh caches) carry
+    # this scope and no block scope in a profiler trace
     with jax.named_scope("layer_scan"):
         if n1 > 1 and n2 > 1:
             # Two-level (sqrt-n) remat: the backward pass keeps the residual
@@ -357,6 +365,44 @@ def _run_group(
         else:
             (x, lb), cache_out = jax.lax.scan(body_fn, init, xs)
     return x, cache_out, lb
+
+
+def _run_group_paged_decode(
+    cfg: ModelConfig,
+    g: GroupSpec,
+    stacked_p: dict,
+    x: jax.Array,
+    positions: jax.Array,
+    *,
+    pools: dict,
+    pos,
+    impl,
+    page_table,
+    paged_impl,
+):
+    """Paged decode's layer scan: the group's stacked pools ride in the
+    carry and each block writes and reads them in place at its layer
+    index, so the pools stay in the (donated) buffers they came in —
+    the other modes' scan would slice every layer's pool out of the
+    stack and write a fresh stack back. Returns (x, pools, lb_sum)."""
+
+    def body(carry, xs):
+        xc, lb_sum, pools = carry
+        p, layer = xs
+        xc, pools, lb = _apply_block(
+            cfg, g.kind, p, xc, positions,
+            mode="decode_paged", cache=pools, pos=pos, impl=impl,
+            page_table=page_table, paged_impl=paged_impl, layer=layer,
+        )
+        return (xc, lb_sum + lb, pools), None
+
+    init = (x, jnp.zeros((), jnp.float32), pools)
+    layers = jnp.arange(g.count, dtype=jnp.int32)
+    # the scan's own operations (slicing each layer's parameters out of
+    # the stacked ones) carry this scope and no block scope in a trace
+    with jax.named_scope("layer_scan"):
+        (x, lb, pools), _ = jax.lax.scan(body, init, (stacked_p, layers))
+    return x, pools, lb
 
 
 def _remat_factors(n: int) -> tuple[int, int]:

@@ -6,7 +6,10 @@ attention_jnp`` (impl=None gather paths) across GQA ratios, dtypes,
 ragged per-slot positions, partially-filled last pages, partially
 allocated page-table rows, and idle slots parked on the trash page.
 The trash page is poisoned with huge values so any masking divergence
-between the two paths is loud, not a rounding blip.
+between the two paths is loud, not a rounding blip. Pools are stacked
+over layers ``(L, n_pages, Hk, page, D)`` with distinct contents per
+layer, as the layer scan passes them, so a read of the wrong layer
+shows too.
 """
 
 import numpy as np
@@ -17,22 +20,24 @@ import jax.numpy as jnp
 from repro.models import layers as L
 
 PAGE, PPS, D = 8, 4, 16  # page size, pages per slot, head dim
+LAYERS = 3  # stacked pool depth; the tests read layers 0 and 2
 ATOL = {jnp.float32: 1e-5, jnp.bfloat16: 1e-2}
 
 
 def _scenario(b, hk, d, *, seed=0, dtype=jnp.float32, trash_slot=True,
               partial_slot=True):
-    """Random pools + page table with the parity suite's edge cases:
-    ragged positions (partial last pages), a partially-allocated row,
-    and an idle slot whose row is all trash page."""
+    """Random stacked pools + page table with the parity suite's edge
+    cases: ragged positions (partial last pages), a partially-allocated
+    row, and an idle slot whose row is all trash page."""
     rng = np.random.default_rng(seed)
     n_pages = b * PPS + 1
-    k = rng.standard_normal((n_pages, hk, PAGE, d))  # head-major pools
-    v = rng.standard_normal((n_pages, hk, PAGE, d))
+    # head-major pools stacked over layers, each layer its own contents
+    k = rng.standard_normal((LAYERS, n_pages, hk, PAGE, d))
+    v = rng.standard_normal((LAYERS, n_pages, hk, PAGE, d))
     # poison the trash page: an unmasked read of page 0 shows up as a
     # huge output delta instead of hiding inside the tolerance
-    k[0] = 1e4
-    v[0] = -1e4
+    k[:, 0] = 1e4
+    v[:, 0] = -1e4
     perm = rng.permutation(np.arange(1, n_pages))
     table = np.zeros((b, PPS), np.int32)
     pos = np.zeros((b,), np.int32)
@@ -81,33 +86,63 @@ def _run(fn, dtype):
     )
 
 
+@pytest.mark.parametrize("layer", [0, 2])
 @pytest.mark.parametrize("g", [1, 2, 4])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_dense_paged_parity(g, dtype):
+def test_dense_paged_parity(g, dtype, layer):
     b, hk = 4, 2
     k, v, table, pos, rng = _scenario(b, hk, D, dtype=dtype)
     q = _q(rng, b, hk, g, D, dtype)
     _run(
         lambda impl: L.paged_decode_attention_jnp(
-            q, k, v, table, pos, sm_scale=D ** -0.5, impl=impl
+            q, k, v, layer, table, pos, sm_scale=D ** -0.5, impl=impl
         ),
         dtype,
     )
 
 
+@pytest.mark.parametrize("layer", [0, 2])
 @pytest.mark.parametrize("g", [1, 2, 4])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_sparse_paged_parity(g, dtype):
+def test_sparse_paged_parity(g, dtype, layer):
     b, hk = 4, 2
     k, v, table, pos, rng = _scenario(b, hk, D, dtype=dtype)
     q = _q(rng, b, hk, g, D, dtype)
     _run(
         lambda impl: L.paged_sparse_decode_attention_jnp(
-            q, k, v, table, pos, sm_scale=D ** -0.5,
+            q, k, v, layer, table, pos, sm_scale=D ** -0.5,
             local_blocks=2, global_blocks=1, impl=impl,
         ),
         dtype,
     )
+
+
+@pytest.mark.parametrize("impl", [None, "interpret"])
+@pytest.mark.parametrize("layer", [0, 2])
+@pytest.mark.parametrize("sparse", [False, True], ids=["dense", "sparse"])
+def test_stacked_layer_read_equals_single_layer_pool(sparse, layer, impl):
+    """Reading layer ``layer`` of the stacked pools gives exactly what the
+    same read gives on that layer's pool alone (``pool[layer][None]`` at
+    layer 0), for both schedules and both reads."""
+    b, hk, g = 4, 2, 2
+    k, v, table, pos, rng = _scenario(b, hk, D)
+    q = _q(rng, b, hk, g, D, jnp.float32)
+    if sparse:
+        def read(kp, vp, li):
+            return L.paged_sparse_decode_attention_jnp(
+                q, kp, vp, li, table, pos, sm_scale=D ** -0.5,
+                local_blocks=2, global_blocks=1, impl=impl,
+            )
+    else:
+        def read(kp, vp, li):
+            return L.paged_decode_attention_jnp(
+                q, kp, vp, li, table, pos, sm_scale=D ** -0.5, impl=impl
+            )
+    got = read(k, v, jnp.int32(layer))
+    want = read(k[layer][None], v[layer][None], 0)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    other = read(k[1][None], v[1][None], 0)  # the layers differ
+    assert not np.allclose(np.asarray(got), np.asarray(other))
 
 
 @pytest.mark.parametrize("seed", [1, 2, 3])
@@ -122,7 +157,7 @@ def test_sparse_parity_random_schedules(seed):
     q = _q(rng2, b, hk, g, D, jnp.float32)
     _run(
         lambda impl: L.paged_sparse_decode_attention_jnp(
-            q, k, v, table, pos, sm_scale=D ** -0.5,
+            q, k, v, seed % LAYERS, table, pos, sm_scale=D ** -0.5,
             local_blocks=local, global_blocks=glob, impl=impl,
         ),
         jnp.float32,
@@ -138,16 +173,19 @@ def test_dense_kernel_matches_contiguous_cache_oracle():
         b, hk, D, trash_slot=False, partial_slot=False
     )
     q = _q(rng, b, hk, g, D, jnp.float32)
+    layer = 1
     got = L.paged_decode_attention_jnp(
-        q, k, v, table, pos, sm_scale=D ** -0.5, impl="interpret"
+        q, k, v, layer, table, pos, sm_scale=D ** -0.5, impl="interpret"
     )
     tbl = np.asarray(table)
     for s in range(b):
         kc = jnp.asarray(
-            np.asarray(k)[tbl[s]].swapaxes(1, 2).reshape(1, PPS * PAGE, hk, D)
+            np.asarray(k)[layer, tbl[s]]
+            .swapaxes(1, 2).reshape(1, PPS * PAGE, hk, D)
         )
         vc = jnp.asarray(
-            np.asarray(v)[tbl[s]].swapaxes(1, 2).reshape(1, PPS * PAGE, hk, D)
+            np.asarray(v)[layer, tbl[s]]
+            .swapaxes(1, 2).reshape(1, PPS * PAGE, hk, D)
         )
         want = L.decode_attention_jnp(
             q[s:s + 1], kc, vc, pos[s], sm_scale=D ** -0.5
@@ -171,13 +209,14 @@ def test_sparse_kernel_covering_schedule_equals_dense():
     table = jnp.asarray(perm.reshape(b, pps).astype(np.int32))
     pos = jnp.asarray(rng.integers(0, pps * PAGE, b).astype(np.int32))
     q = _q(rng, b, hk, g, D, jnp.float32)
-    k, v = jnp.asarray(k, jnp.float32), jnp.asarray(v, jnp.float32)
+    # one layer's pools, passed as a stack of one
+    k, v = jnp.asarray(k, jnp.float32)[None], jnp.asarray(v, jnp.float32)[None]
     got = L.paged_sparse_decode_attention_jnp(
-        q, k, v, table, pos, sm_scale=D ** -0.5,
+        q, k, v, 0, table, pos, sm_scale=D ** -0.5,
         local_blocks=2, global_blocks=1, impl="interpret",
     )
     want = L.paged_decode_attention_jnp(
-        q, k, v, table, pos, sm_scale=D ** -0.5
+        q, k, v, 0, table, pos, sm_scale=D ** -0.5
     )
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), atol=1e-5, rtol=1e-5
@@ -192,11 +231,11 @@ def test_trash_page_slot_is_benign():
     k, v, table, pos, rng = _scenario(b, hk, D)  # slot 1 is all-trash
     q = _q(rng, b, hk, g, D, jnp.float32)
     got = L.paged_sparse_decode_attention_jnp(
-        q, k, v, table, pos, sm_scale=D ** -0.5,
+        q, k, v, 2, table, pos, sm_scale=D ** -0.5,
         local_blocks=2, global_blocks=1, impl="interpret",
     )
     ref = L.paged_sparse_decode_attention_jnp(
-        q, k, v, table, pos, sm_scale=D ** -0.5,
+        q, k, v, 2, table, pos, sm_scale=D ** -0.5,
         local_blocks=2, global_blocks=1,
     )
     assert np.isfinite(np.asarray(got)).all()
@@ -228,3 +267,77 @@ def test_paged_sparse_schedule_properties():
         for t in range(idx.shape[1]):
             assert bool(keep[s, t]) == (idx[s, t] not in seen)
             seen.add(idx[s, t])
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+def test_write_paged_token_touches_only_its_rows(layer):
+    """The decode step's token write: active slots write one row of a
+    page of their own, idle slots all alias the trash page 0. Written
+    rows equal the new K/V bit for bit; every other row of every page,
+    and every page of the other layers, stays bit-identical."""
+    rng = np.random.default_rng(21)
+    b, hk, n_pages = 6, 2, 9
+    pool = jnp.asarray(
+        rng.standard_normal((LAYERS, n_pages, hk, PAGE, D)), jnp.bfloat16
+    )
+    phys = np.array([3, 0, 7, 0, 5, 0], np.int32)  # slots 1, 3, 5 idle
+    off = np.array([0, 0, PAGE - 1, 0, 3, 0], np.int32)
+    new = jnp.asarray(rng.standard_normal((b, hk, D)), jnp.float32)
+    got = np.asarray(
+        L.write_paged_token(
+            pool, jnp.int32(layer), jnp.asarray(phys), jnp.asarray(off), new
+        ),
+        np.float32,
+    )
+    want = np.asarray(pool, np.float32).copy()
+    new_bf16 = np.asarray(new.astype(jnp.bfloat16), np.float32)
+    for s in (0, 2, 4):
+        want[layer, phys[s], :, off[s]] = new_bf16[s]
+    trash = np.zeros(n_pages, bool)
+    trash[0] = True
+    # the trash page takes one of the idle slots' rows: never read back
+    np.testing.assert_array_equal(got[:, ~trash], want[:, ~trash])
+    np.testing.assert_array_equal(
+        np.delete(got, layer, axis=0), np.delete(want, layer, axis=0)
+    )
+    idle_rows = [new_bf16[s] for s in (1, 3, 5)]
+    assert any(np.array_equal(got[layer, 0, :, 0], r) for r in idle_rows)
+    np.testing.assert_array_equal(got[layer, 0, :, 1:], want[layer, 0, :, 1:])
+
+
+def test_decode_step_writes_each_layer_in_place():
+    """A whole paged decode step through the layer scan: every layer's
+    pool changes only in the rows the active slots write (and the trash
+    page), and every layer takes its own K/V there."""
+    import jax
+
+    from repro.configs import registry
+    from repro.models import transformer as T
+
+    cfg = registry.get_smoke("qwen3-1.7b", sparse=True)
+    page, per_seq, slots = cfg.attn_block, 2, 4
+    n_pages = slots * per_seq + 1
+    params = T.init_model(jax.random.PRNGKey(0), cfg)
+    rng = np.random.default_rng(3)
+    pools = jax.tree.map(
+        lambda a: jnp.asarray(rng.standard_normal(a.shape), a.dtype),
+        T.init_paged_cache(cfg, n_pages, page),
+    )
+    table = np.zeros((slots, per_seq), np.int32)
+    table[0] = [1, 2]
+    table[2] = [5, 6]  # slots 1 and 3 idle: all-trash rows
+    pos = np.array([page + 4, 0, 9, 0], np.int32)
+    before = jax.tree.map(np.asarray, pools)
+    _, after = T.decode_step_paged(
+        cfg, params, pools, jnp.asarray([1, 2, 3, 4], jnp.int32),
+        jnp.asarray(pos), jnp.asarray(table), paged_impl="interpret",
+    )
+    written = {(2, 4), (5, 9)}  # (physical page, row) of slots 0 and 2
+    for old, new in zip(jax.tree.leaves(before), jax.tree.leaves(after)):
+        new = np.asarray(new)
+        changed = np.argwhere((old != new).any(axis=(2, 4)))  # (l, page, row)
+        rows = {(int(p), int(r)) for _, p, r in changed if p != 0}
+        assert rows == written
+        for p, r in written:  # each layer wrote its own K/V
+            rows_by_layer = {layer.tobytes() for layer in new[:, p, :, r]}
+            assert len(rows_by_layer) == len(new)
