@@ -2,27 +2,39 @@
 does, warm the cell's programs, drive an open loop on the wall clock,
 then check the served tokens against the plain reference.
 
-Everything a cell needs is found by name: ``BENCHMARK.json`` names the
-cell's configuration and traffic; ``bench/configs/<config>.json``,
-``bench/traffic/<traffic>.json`` and ``bench/cells/<cell>.json`` hold
-them; each per-layer metric is read by ``bench/metrics/<metric>.py``.
+Everything a cell needs is found by name, so a cell, a configuration, an
+architecture, an arrival kind or a per-layer metric is added as new
+files and entries alone:
+
+- ``BENCHMARK.json`` names the cell's configuration and traffic;
+  ``bench/configs/<config>.json``, ``bench/traffic/<traffic>.json`` and
+  ``bench/cells/<cell>.json`` hold them;
+- the configuration's ``architectures[0]``, in lower case, names its
+  module: ``bench/arch/<architecture>.py`` (``Qwen3ForCausalLM`` ->
+  ``bench/arch/qwen3forcausallm.py``), which gives the program's
+  configuration, the plain reference and the work counts (see
+  ``bench.arch``);
+- the traffic's ``arrivals.kind`` names ``bench/traffic/arrivals/<kind>.py``
+  (see ``bench.traffic.generator``);
+- each per-layer metric is read by ``bench/metrics/<metric>.py``.
 """
 
 from __future__ import annotations
 
 import contextlib
 import dataclasses
-import importlib.util
 import json
 import math
 import re
 import time
 from pathlib import Path
+from types import ModuleType
 
 import numpy as np
 
-from bench.reference import ModelSpec, Reference
+from bench import loader
 from bench.traffic import generator
+from bench.weights import tree_bytes
 
 ROOT = Path(__file__).resolve().parents[1]
 # room on the chip beyond what the hungrier program needs: the runtime's
@@ -49,6 +61,7 @@ class Cell:
     cell: dict
     end_to_end: list[dict]
     per_layer: list[dict]
+    arch: ModuleType  # the configuration's architecture module (bench.arch)
 
 
 def load_benchmark(root: Path = ROOT) -> dict:
@@ -65,89 +78,32 @@ def resolve_cell(bench: dict, name: str, root: Path = ROOT) -> Cell:
         raise KeyError(f"unknown workload {name!r}; known: {sorted(wl)}")
     w = wl[name]
     conf = {c["name"]: c for c in bench["configs"]}[w["config"]]
+    config = json.loads((root / conf["file"]).read_text())
     return Cell(
         name=name,
         chips=int(w["chips"]),
-        config=json.loads((root / conf["file"]).read_text()),
+        config=config,
         mix=generator.load_mix(w["traffic"], root / "bench" / "traffic"),
         cell=json.loads((root / "bench" / "cells" / f"{name}.json").read_text()),
         end_to_end=[m for m in bench["end_to_end"] if _in_cell(m, name)],
         per_layer=[m for m in bench["per_layer"] if _in_cell(m, name)],
+        arch=arch_module(config, root),
     )
+
+
+def arch_module(conf: dict, root: Path = ROOT) -> ModuleType:
+    """``bench/arch/<architectures[0] in lower case>.py`` of a
+    configuration file."""
+    name = conf["architectures"][0].lower()
+    if not re.fullmatch(r"[a-z0-9_]+", name):
+        raise ValueError(f"architecture {conf['architectures'][0]!r} names no module")
+    return loader.load(root / "bench" / "arch" / f"{name}.py")
 
 
 def metric_reader(name: str, root: Path = ROOT):
     """``read(run, trace)`` of ``bench/metrics/<name>.py``: the metric's
     value, or None where the run offers nothing to read it from."""
-    path = root / "bench" / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
-
-
-def model_spec(conf: dict) -> ModelSpec:
-    """The reference's view of a configuration file (published keys)."""
-    px = conf.get("pixelfly") or {}
-    return ModelSpec(
-        layers=conf["num_hidden_layers"],
-        d_model=conf["hidden_size"],
-        heads=conf["num_attention_heads"],
-        kv_heads=conf["num_key_value_heads"],
-        head_dim=conf["head_dim"],
-        d_ff=conf["intermediate_size"],
-        vocab=conf["vocab_size"],
-        rope_theta=float(conf["rope_theta"]),
-        norm_eps=float(conf["rms_norm_eps"]),
-        sparse=bool(px),
-        density=px.get("sparse_density", 0.2),
-        block=px.get("sparse_block", 128),
-        lowrank_frac=px.get("lowrank_frac", 0.25),
-        attn_block=px.get("attn_block", 128),
-        local_blocks=px.get("attn_local_blocks", 2),
-        global_blocks=px.get("attn_global_blocks", 1),
-    )
-
-
-def program_config(conf: dict, *, smoke: dict | None = None):
-    """The program's ``ModelConfig`` for a configuration file, through
-    its registry, checked against every published width. ``smoke``
-    replaces widths for CPU tests (and the check with it)."""
-    from repro.configs import registry
-
-    px = conf.get("pixelfly") or {}
-    over = {"norm_eps": float(conf["rms_norm_eps"])}
-    if px:
-        over.update(
-            sparse_density=px["sparse_density"],
-            sparse_block=px["sparse_block"],
-            lowrank_frac=px["lowrank_frac"],
-            attn_local_blocks=px["attn_local_blocks"],
-            attn_global_blocks=px["attn_global_blocks"],
-            attn_block=px["attn_block"],
-        )
-    over.update(smoke or {})
-    cfg = registry.get(conf["arch"], sparse=bool(px), **over)
-    want = {
-        "num_layers": conf["num_hidden_layers"],
-        "d_model": conf["hidden_size"],
-        "num_heads": conf["num_attention_heads"],
-        "num_kv_heads": conf["num_key_value_heads"],
-        "head_dim": conf["head_dim"],
-        "d_ff": conf["intermediate_size"],
-        "vocab_size": conf["vocab_size"],
-        "rope_theta": float(conf["rope_theta"]),
-        "qk_norm": True,
-        "tie_embeddings": conf["tie_word_embeddings"],
-        "dtype": conf["torch_dtype"],
-        "sparse_attention": bool(px),
-        "attn_max_stride": 0,
-    }
-    got = {k: getattr(cfg, k) for k in want}
-    if got != want:
-        bad = {k: (got[k], want[k]) for k in want if got[k] != want[k]}
-        raise ValueError(f"registry config differs from the file: {bad}")
-    return cfg
+    return loader.load(root / "bench" / "metrics" / f"{name}.py").read
 
 
 # ----------------------------------------------------------------------
@@ -182,17 +138,15 @@ def engine_config(mix: generator.Mix, n_pages: int):
     )
 
 
-def page_bytes(cfg) -> int:
-    return 2 * cfg.num_layers * cfg.num_kv_heads * cfg.attn_block * cfg.head_dim * np.dtype(cfg.dtype).itemsize
-
-
 def size_pool(cfg, mesh, param_shapes, mix, device) -> dict:
     """Pages for the pool: what the chip holds after the parameters and
     the temporaries of the decode step and the largest prefill program,
-    as ``memory_analysis()`` of those programs reports them. Temporaries
-    grow with the pool (the decode step copies it), so the pages are
-    first estimated from two small probe pools, then the programs are
-    compiled at the estimate and it is cut until both fit."""
+    as ``memory_analysis()`` of those programs reports them, so any
+    state the program keeps beside the pools is counted. Each program's
+    need is taken as affine in the pages: estimated from two small probe
+    pools, then the programs are compiled at the estimate and it is cut
+    until both fit. A page's bytes are read from the program's own pool
+    shapes (``init_paged_cache`` at the two probe sizes)."""
     import jax
     import jax.numpy as jnp
 
@@ -218,9 +172,12 @@ def size_pool(cfg, mesh, param_shapes, mix, device) -> dict:
         donate_argnums=(3,),
     )
 
+    def pool_shapes(n_pages):
+        return jax.eval_shape(lambda: T.init_paged_cache(c, n_pages, page))
+
     def need(n_pages) -> list[int]:
         """Bytes the device holds while each program runs."""
-        pool = jax.eval_shape(lambda: T.init_paged_cache(c, n_pages, page))
+        pool = pool_shapes(n_pages)
         with mesh:
             progs = (
                 dec.lower(param_shapes, pool, i32(slots), i32(slots), i32(slots, per_seq)),
@@ -235,8 +192,9 @@ def size_pool(cfg, mesh, param_shapes, mix, device) -> dict:
 
     limit = device.memory_stats()["bytes_limit"]
     budget = limit - POOL_RESERVE_BYTES
-    params = sum(math.prod(x.shape) * x.dtype.itemsize for x in jax.tree.leaves(param_shapes))
+    params = tree_bytes(param_shapes)
     lo, hi = per_seq + 1, 2 * per_seq + 1
+    page_bytes = (tree_bytes(pool_shapes(hi)) - tree_bytes(pool_shapes(lo))) // (hi - lo)
     # each program's need is affine in the pages: fit both, take the
     # tighter, then correct the estimate by what the compiler reports
     lines = [(a, (b - a) / (hi - lo)) for a, b in zip(need(lo), need(hi))]
@@ -262,7 +220,7 @@ def size_pool(cfg, mesh, param_shapes, mix, device) -> dict:
         )
     else:
         raise RuntimeError(f"no pool size fits {budget} bytes: {tries}")
-    return {"n_pages": n, "bytes_limit": limit, "param_bytes": params, "tries": tries}
+    return {"n_pages": n, "page_bytes": page_bytes, "bytes_limit": limit, "param_bytes": params, "tries": tries}
 
 
 def build_engine(cfg, mesh, params, mix, n_pages: int, paged_impl=None):
@@ -337,11 +295,37 @@ class Window:
         return self.close - self.open
 
 
+def admit_in_setup(engine, sched: generator.Schedule) -> list[Req]:
+    """Submit the schedule's first ``sched.in_setup`` requests and take
+    one step, which admits as many of them as the pool holds (an
+    admission pass plans every waiting request its budget fits) and
+    decodes them once. Part of set-up: the tokens it delivers are
+    stamped before the window opens, where no metric counts them."""
+    if not sched.in_setup:
+        return []
+    clock = time.perf_counter
+    reqs = []
+    for i in range(sched.in_setup):
+        r = Req(i, math.nan, int(sched.prompt_len[i]), int(sched.output_len[i]))
+        r.uid = engine.submit(sched.prompts[i], r.olen)
+        r.submit = clock()
+        reqs.append(r)
+    fins = engine.step()
+    t = clock()
+    if fins or engine.scheduler.num_free_slots == engine.ecfg.max_slots:
+        raise RuntimeError(f"set-up admitted {len(fins)} finished and no running request")
+    counts = {st.request.uid: len(st.generated) for st in engine.scheduler.active()}
+    for r in reqs:
+        r.times = [t] * counts.get(r.uid, 0)
+    return reqs
+
+
 def open_loop(engine, sched: generator.Schedule, seconds: float, *, t_open: float | None = None,
-              annotate: bool = False, hooks=None) -> Window:
+              annotate: bool = False, hooks=None, started: list[Req] | None = None) -> Window:
     """Submit each request when due, step while there is work, sleep
     only while the engine is idle. Tokens are stamped at the end of the
-    step that produced them."""
+    step that produced them. ``started`` are the schedule's first
+    requests, submitted during set-up (``admit_in_setup``)."""
     import jax
 
     from repro.analysis.guards import compile_events_total
@@ -351,14 +335,16 @@ def open_loop(engine, sched: generator.Schedule, seconds: float, *, t_open: floa
     clock = time.perf_counter
     t_open = clock() if t_open is None else t_open
     close = t_open + seconds
-    reqs: list[Req] = []
-    by_uid: dict[int, Req] = {}
+    reqs: list[Req] = list(started or [])
+    by_uid: dict[int, Req] = {r.uid: r for r in reqs}
     pending = [
         Req(i, t_open + float(d), int(p), int(o))
         for i, (d, p, o) in enumerate(zip(sched.due_s, sched.prompt_len, sched.output_len))
     ]
+    for r in reqs:
+        r.due = pending[r.idx].due
     prompts = sched.prompts
-    nxt = 0
+    nxt = len(reqs)
     steps: list[Step] = []
     c0 = compile_events_total()
     hooks = hooks or {}
@@ -443,13 +429,13 @@ def ttft_ms(win: Window) -> list[float]:
 
 
 def itl_ms(win: Window) -> list[float]:
-    """Gaps between consecutive tokens of a request, for every token
-    delivered in the window that has one before it."""
+    """Gaps between consecutive tokens of a request, for every pair of
+    tokens delivered in the window."""
     out = []
     for r in win.reqs:
         ts = r.times
         for a, b in zip(ts, ts[1:]):
-            if win.open <= b <= win.close:
+            if win.open <= a and b <= win.close:
                 out.append((b - a) * 1e3)
     return out
 

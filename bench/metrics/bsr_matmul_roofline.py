@@ -1,11 +1,11 @@
 """Share of its roofline that the block-sparse matmul kernel reaches
 inside the decode program: the least time for the nonzero blocks and
-the active slots' rows (``bench.work``), over the kernel's device time
-in the traced decode steps."""
+the active slots' rows (the architecture's work counts, ``bench.arch``),
+over the kernel's device time in the traced decode steps."""
 
 
 def read(run, trace):
-    if trace is None or run["peaks"] is None:
+    if trace is None or run["peaks"] is None or not hasattr(run["work"], "bsr_matmul"):
         return None
     steps, work, peaks = run["window"].steps, run["work"], run["peaks"]
     need = spent = 0.0

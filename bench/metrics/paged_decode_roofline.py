@@ -1,11 +1,11 @@
 """Share of its roofline that the paged decode kernel reaches: the least
 time the chip needs for the pages the pattern makes each active slot
-read (``bench.work``), over the kernel's device time in the traced
-decode steps."""
+read (the architecture's work counts, ``bench.arch``), over the kernel's
+device time in the traced decode steps."""
 
 
 def read(run, trace):
-    if trace is None or run["peaks"] is None:
+    if trace is None or run["peaks"] is None or not hasattr(run["work"], "paged_decode"):
         return None
     steps, work, peaks = run["window"].steps, run["work"], run["peaks"]
     need = spent = 0.0

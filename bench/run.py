@@ -54,7 +54,8 @@ def main(argv=None) -> int:
         )
         return 3
     result, compared = session.run(cell, args.seed, args.seconds, bool(args.trace), T_START)
-    print(f"window compile events: {result.pop('_compiles')}", flush=True)
+    print(f"window compile events: {result.pop('_compiles')}; "
+          f"waiting at close: {result.pop('_waiting_at_close')}", flush=True)
     for line in compared["lines"]:
         print(line, file=sys.stderr)
     sys.stderr.flush()

@@ -20,14 +20,10 @@ import time
 from pathlib import Path
 from typing import Callable
 
-import numpy as np
-
 from bench import harness
 from bench.peaks import peaks_for
-from bench.reference import Reference
 from bench.traffic import generator
 from bench.weights import LayerWeights, make_params
-from bench.work import Work
 
 __all__ = ["TestOptions", "use_compile_cache", "run"]
 
@@ -109,8 +105,9 @@ def run(cell: harness.Cell, seed: int, seconds: float, trace: bool, t_start: flo
     from repro.models import transformer as T
 
     conf = {**cell.config, **(test.widths if test else {})}
-    cfg = harness.program_config(conf, smoke=test.program if test else None)
-    spec = harness.model_spec(conf)
+    arch = cell.arch
+    cfg = arch.program_config(conf, smoke=test.program if test else None)
+    vocab = conf["vocab_size"]
     mix = cell.mix
     devs = jax.devices()[: cell.chips]
     dev = devs[0]
@@ -124,7 +121,7 @@ def run(cell: harness.Cell, seed: int, seconds: float, trace: bool, t_start: flo
         marks.append((name, time.perf_counter()))
 
     param_shapes = jax.eval_shape(lambda k: T.init_model(k, cfg), jax.random.PRNGKey(0))
-    params = make_params(param_shapes, seed, spec.vocab)
+    params = make_params(param_shapes, seed, vocab)
     jax.block_until_ready(params)
     mark("weights")
     if test:
@@ -141,20 +138,28 @@ def run(cell: harness.Cell, seed: int, seconds: float, trace: bool, t_start: flo
     mark("engine")
     harness.warm(engine, mix)
     mark("warm")
-    sched = generator.schedule(mix, seed, seconds, spec.vocab, rate=rate)
+    sched = generator.schedule(mix, seed, seconds, vocab, rate=rate)
+    started = harness.admit_in_setup(engine, sched)
+    if started:
+        mark("first wave")
     engine.reset_stats()  # the counters read are the window's
     if test and test.fault:
         test.fault(engine)
     setup_s = time.perf_counter() - t_start
     phases = ", ".join(f"{b[0]} {b[1] - a[1]:.2f} s" for a, b in zip(marks, marks[1:]))
-    print(f"set-up: {phases}; pool {pool['n_pages']} pages", file=sys.stderr, flush=True)
+    note = f"pool {pool['n_pages']} pages"
+    if "page_bytes" in pool:
+        note += f" of {pool['page_bytes']} B"
+    if started:
+        note += f"; {sum(r.count > 0 for r in started)} of {len(started)} admitted in set-up"
+    print(f"set-up: {phases}; {note}", file=sys.stderr, flush=True)
 
     # ---- the window ----------------------------------------------------
     t_open = time.perf_counter()
     tracer = _Tracer(t_open, seconds, trace_dir) if trace else None
     win = harness.open_loop(
         engine, sched, seconds, t_open=t_open, annotate=trace,
-        hooks={"tick": tracer.tick} if tracer else None,
+        hooks={"tick": tracer.tick} if tracer else None, started=started,
     )
     if tracer:
         tracer.finish()
@@ -170,9 +175,9 @@ def run(cell: harness.Cell, seed: int, seconds: float, trace: bool, t_start: flo
     gc.collect()
 
     # ---- correctness -----------------------------------------------------
-    weights = LayerWeights(param_shapes, seed, spec.vocab)
-    ref = Reference(spec, weights, mix.engine["max_len"])
-    ctl = Reference(spec, weights, mix.engine["max_len"], precision="fp8") if control else None
+    weights = LayerWeights(param_shapes, seed, vocab)
+    ref = arch.reference(conf, weights, mix.engine["max_len"])
+    ctl = arch.reference(conf, weights, mix.engine["max_len"], precision="fp8") if control else None
     sample = harness.pick_sample(win, seed)
     widest = program_widest = math.inf
     n_tok = 0
@@ -191,7 +196,7 @@ def run(cell: harness.Cell, seed: int, seconds: float, trace: bool, t_start: flo
     compared = {"served_logit_gap_max": {"value": widest, "limit": limit}}
 
     # ---- metrics -------------------------------------------------------
-    work = Work(spec, mix.engine["max_len"])
+    work = arch.work(conf, mix.engine["max_len"])
     record = {"window": win, "counters": counters, "work": work, "peaks": peaks, "chips": cell.chips,
               # the seconds in which starting the profiler held the loop
               "profiler_start": (tracer.begin, tracer.t0) if tracer and tracer.t0 else None}
@@ -228,6 +233,7 @@ def run(cell: harness.Cell, seed: int, seconds: float, trace: bool, t_start: flo
     if breakdown is not None:
         result["breakdown"] = breakdown
     result["_compiles"] = win.compiles
+    result["_waiting_at_close"] = win.waiting_at_close
     result["compared"] = compared
     lines = [f"reference sample: {len(sample)} requests, {n_tok} served tokens"]
     lines += [f"compared {k}: {v['value']} (limit {v['limit']})" for k, v in compared.items()]

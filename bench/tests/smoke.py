@@ -39,6 +39,15 @@ MIXES = {
         output_len={"median": 8, "sigma": 0.7, "min": 2, "max": 24},
         engine={"max_slots": 4, "max_len": 320, "max_prefill_batch": 2},
     ),
+    # an offline backlog that outlasts a short window: long prompts
+    # and answers for the size, a first wave admitted in set-up
+    "longctx": generator.Mix(
+        name="longctx-smoke",
+        arrivals={"kind": "backlog", "count": 160},
+        prompt_len={"median": 160, "sigma": 0.5, "min": 96, "max": 256},
+        output_len={"median": 24, "sigma": 0.6, "min": 8, "max": 48},
+        engine={"max_slots": 4, "max_len": 320, "max_prefill_batch": 1},
+    ),
 }
 
 

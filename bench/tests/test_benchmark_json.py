@@ -1,13 +1,15 @@
 """``BENCHMARK.json`` and every file it names: names, units and keys as
 the benchmark's contract has them, every per-layer metric's cells
 reporting the end-to-end metric it moves, and a new configuration,
-traffic mix and per-layer metric added as new files and entries alone."""
+traffic mix, per-layer metric, architecture and arrival kind added as
+new files and entries alone."""
 
 import json
 import re
 import shutil
 
 import numpy as np
+import pytest
 
 from bench import harness
 from bench.traffic import generator
@@ -78,7 +80,8 @@ def test_every_named_file_loads():
         conf = json.loads((ROOT / c["file"]).read_text())
         assert conf["name"] == c["name"] and conf["source"] == c["source"]
         assert conf["reduced"] == c["reduced"]
-        harness.model_spec(conf)
+        arch = harness.arch_module(conf)
+        assert all(callable(getattr(arch, f)) for f in ("program_config", "reference", "work"))
     for w in BENCH["workloads"]:
         cell = harness.resolve_cell(BENCH, w["name"])
         assert cell.cell["served_logit_gap_limit"] > 0
@@ -113,7 +116,7 @@ def test_every_cell_reports_what_its_metrics_move():
 def test_a_cell_is_added_by_files_and_entries_alone(tmp_path):
     """A new configuration, traffic mix, cell and per-layer metric: new
     files and new entries in a copy of the benchmark, no file edited."""
-    for d in ("configs", "traffic", "cells", "metrics"):
+    for d in ("configs", "traffic", "cells", "metrics", "arch"):
         shutil.copytree(ROOT / "bench" / d, tmp_path / "bench" / d)
     bench = json.loads(json.dumps(BENCH))
     before = {p: p.read_bytes() for p in (tmp_path / "bench").rglob("*") if p.is_file()}
@@ -147,3 +150,138 @@ def test_a_cell_is_added_by_files_and_entries_alone(tmp_path):
     after = {p: p.read_bytes() for p in before}
     assert after == before
     assert np.all(np.diff(s.due_s) >= 0)
+
+
+TOY_ARCH = """
+\"\"\"A toy architecture for the tests: the token embedding, two residual
+SwiGLU layers, logits against the embedding.\"\"\"
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+
+def program_config(conf, *, smoke=None):
+    from repro.configs import registry
+
+    return registry.get_smoke("qwen3-1.7b").replace(
+        num_layers=2, vocab_size=conf["vocab_size"], dtype="float32", **(smoke or {}))
+
+
+class Reference:
+    def __init__(self, weights, vocab):
+        self.weights, self.vocab = weights, vocab
+
+    def logits(self, tokens, read):
+        return self.logits_many([tokens], [read])[0]
+
+    def logits_many(self, seqs, reads):
+        emb = self.weights.top()["embed/tok"][: self.vocab]
+        layers = [self.weights.layer(i) for i in range(2)]
+        out = []
+        for tokens, read in zip(seqs, reads):
+            x = emb[jnp.asarray(tokens)]
+            for w in layers:
+                x = x + (jax.nn.silu(x @ w["mlp/wg/w"]) * (x @ w["mlp/wu/w"])) @ w["mlp/wd/w"]
+            out.append(np.asarray(x[jnp.asarray(read)] @ emb.T))
+        return out
+
+
+def reference(conf, weights, max_len, *, precision="f32"):
+    return Reference(weights, conf["vocab_size"])
+
+
+class Work:
+    def __init__(self, conf):
+        self.per_token = 2 * 3 * 2 * conf["hidden_size"] * conf["intermediate_size"]
+
+    def prefill_flops(self, n):
+        return self.per_token * n
+
+    def decode_flops(self, p):
+        return self.per_token
+
+
+def work(conf, max_len):
+    return Work(conf)
+"""
+
+BURST_ARRIVALS = """
+\"\"\"Bursts of ``size`` requests every ``every_s`` seconds.\"\"\"
+
+import numpy as np
+
+from bench.traffic.generator import SCHEDULE_SEED, Schedule, draw_prompts, lengths, seed_rng
+
+
+def schedule(mix, seed, seconds, vocab):
+    a = mix.arrivals
+    starts = np.arange(0.0, seconds, a["every_s"])
+    due = np.repeat(starts, a["size"])
+    plen = lengths(mix.prompt_len, due.size, seed_rng(SCHEDULE_SEED, 1))
+    olen = lengths(mix.output_len, due.size, seed_rng(SCHEDULE_SEED, 2))
+    return Schedule(due, plen, olen, draw_prompts(plen, seed, vocab))
+"""
+
+
+def test_an_architecture_and_an_arrival_kind_are_added_by_files_alone(tmp_path):
+    """A configuration whose architecture is a module of its own and a mix
+    whose arrival kind is a module of its own: new files and entries in a
+    copy of the benchmark resolve to a cell whose schedule, program
+    configuration, reference and work counts the harness builds, with no
+    file edited."""
+    import jax
+
+    from bench.weights import LayerWeights
+
+    for d in ("configs", "traffic", "cells", "metrics", "arch"):
+        shutil.copytree(ROOT / "bench" / d, tmp_path / "bench" / d,
+                        ignore=shutil.ignore_patterns("__pycache__"))
+    bench = json.loads(json.dumps(BENCH))
+    before = {p: p.read_bytes() for p in (tmp_path / "bench").rglob("*") if p.is_file()}
+    conf = {"name": "toy-2l", "source": "https://example.org/toy-2l", "architectures": ["ToyTwoLayerLM"],
+            "hidden_size": 256, "intermediate_size": 512, "vocab_size": 96, "reduced": []}
+    (tmp_path / "bench/configs/toy-2l.json").write_text(json.dumps(conf))
+    (tmp_path / "bench/arch/toytwolayerlm.py").write_text(TOY_ARCH)
+    (tmp_path / "bench/traffic/arrivals/burst.py").write_text(BURST_ARRIVALS)
+    mix = json.loads((ROOT / "bench/traffic" / f"{BENCH['workloads'][0]['traffic']}.json").read_text())
+    mix["arrivals"] = {"kind": "burst", "every_s": 2.5, "size": 3}
+    (tmp_path / "bench/traffic/chat-bursts.json").write_text(json.dumps(mix))
+    (tmp_path / "bench/cells/toy-bursts.json").write_text(json.dumps({"served_logit_gap_limit": 0.1}))
+    bench["configs"].append({"name": "toy-2l", "source": conf["source"], "file": "bench/configs/toy-2l.json",
+                             "reduced": [], "why": "a toy"})
+    bench["workloads"].append({"name": "toy-bursts", "config": "toy-2l", "traffic": "chat-bursts",
+                               "chips": 1, "why": "bursty chat on a toy"})
+    cell = harness.resolve_cell(bench, "toy-bursts", root=tmp_path)
+    assert cell.arch.__file__ == str(tmp_path / "bench/arch/toytwolayerlm.py")
+
+    s = generator.schedule(cell.mix, 5, 10.0, conf["vocab_size"])
+    np.testing.assert_array_equal(s.due_s, np.repeat([0.0, 2.5, 5.0, 7.5], 3))
+    assert all(int(p.max()) < conf["vocab_size"] for p in s.prompts)
+
+    from repro.models import transformer as T
+
+    cfg = cell.arch.program_config(cell.config)
+    shapes = jax.eval_shape(lambda k: T.init_model(k, cfg), jax.random.PRNGKey(0))
+    ref = cell.arch.reference(cell.config, LayerWeights(shapes, 5, conf["vocab_size"]), 320)
+    lg = ref.logits(s.prompts[0], np.arange(s.prompts[0].size))
+    assert lg.shape == (s.prompts[0].size, conf["vocab_size"]) and np.isfinite(lg).all()
+
+    # the metrics read the counts the module defines, and nothing for one
+    # it lacks
+    work = cell.arch.work(cell.config, 320)
+    steps = [harness.Step(0.0, 0.5, prefills=[100], prefill_tokens=100), harness.Step(0.5, 1.0, decode_positions=[100])]
+    win = harness.Window(0.0, 2.0, [], steps, 0)
+
+    class Peaks:
+        flops, hbm_bytes = 1e9, 1e9
+
+    class Trace:
+        steps = {1: type("S", (), {"paged_ms": 1.0, "bsr_decode_ms": 1.0})()}
+
+    run = {"window": win, "work": work, "peaks": Peaks, "chips": 1}
+    assert harness.metric_reader("mfu", tmp_path)(run, None) == pytest.approx(100 * work.per_token * 101 / 1e9)
+    assert harness.metric_reader("paged_decode_roofline", tmp_path)(run, Trace) is None
+    assert harness.metric_reader("bsr_matmul_roofline", tmp_path)(run, Trace) is None
+    after = {p: p.read_bytes() for p in before}
+    assert after == before

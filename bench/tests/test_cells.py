@@ -1,6 +1,7 @@
 """The harness's cell loop on the CPU at small widths: the chat cell of
-both twins, through set-up, the open-loop window and the check against
-the reference, with the profiler off and on."""
+both twins and the pixelfly backlog cell, through set-up, the open-loop
+window and the check against the reference, with the profiler off and
+on."""
 
 import math
 import time
@@ -53,6 +54,34 @@ def test_cell_loop(like, trace):
             assert v is None
         else:
             assert v is not None and v >= 0, m["name"]
+
+
+def test_backlog_cell_loop():
+    """The backlog: its first wave is submitted and admitted in set-up and
+    its tokens before the open count toward no metric; the queue is
+    never empty at the close; warm-up covered every prefill program."""
+    from bench import harness
+
+    c = smoke.cell("pixelfly-longctx", "longctx")
+    result, extra = session.run(c, 2**31 + 77, 3.0, False, time.perf_counter(), test=smoke.options(c))
+    assert result["correct"], extra["lines"]
+    assert result["_compiles"] == 0
+    assert set(result["metrics"]) == {"itl_p95_ms", "output_tokens_per_s", "setup_s"}
+    win = extra["record"]["window"]
+    first = win.reqs[: c.mix.engine["max_slots"]]
+    assert [r.idx for r in first] == list(range(len(first)))
+    assert all(r.submit < win.open for r in first)
+    assert all(r.submit >= win.open for r in win.reqs[len(first):])
+    admitted = [r for r in first if r.times and r.times[0] < win.open]
+    assert admitted, "no request was admitted in set-up"
+    assert win.waiting_at_close > 0
+    assert len(win.reqs) == c.mix.arrivals["count"]
+    in_window = [t for r in win.reqs for t in r.times if win.open <= t <= win.close]
+    assert sum(len(r.times) for r in win.reqs) > len(in_window)
+    got = result["metrics"]["output_tokens_per_s"]["value"]
+    assert got == pytest.approx(len(in_window) / win.seconds)
+    gaps = [b - a for r in win.reqs for a, b in zip(r.times, r.times[1:]) if win.open <= a and b <= win.close]
+    assert len(harness.itl_ms(win)) == len(gaps) > 0
 
 
 def test_wait_readers_leave_out_the_profiler_start():

@@ -48,3 +48,15 @@ def test_fault_is_not_correct(like, fault):
     )
     assert not result["correct"]
     assert result["compared"]["served_logit_gap_max"]["value"] > c.cell["served_logit_gap_limit"]
+
+
+@pytest.mark.parametrize("fault", [alter_tokens, freeze_state], ids=["token_altered", "state_unchanged"])
+def test_backlog_fault_is_not_correct(fault):
+    """The same faults in the backlog cell, planted after its first wave
+    was admitted in set-up."""
+    c = smoke.cell("pixelfly-longctx", "longctx")
+    result, _ = session.run(
+        c, 2**31 + 4243, 3.0, False, time.perf_counter(), test=smoke.options(c, fault=fault)
+    )
+    assert not result["correct"]
+    assert result["compared"]["served_logit_gap_max"]["value"] > c.cell["served_logit_gap_limit"]

@@ -7,8 +7,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import harness
-from bench.reference import Reference
+from bench.arch import qwen3forcausallm as Q
+from bench.arch.qwen3forcausallm import Reference
 from bench.tests import smoke
 from bench.weights import LayerWeights, make_params
 
@@ -22,8 +22,8 @@ def _setup(like):
     c = smoke.cell(like, "chat")
     o = smoke.options(c)
     conf = {**c.config, **o.widths}
-    cfg = harness.program_config(conf, smoke=o.program)
-    spec = harness.model_spec(conf)
+    cfg = c.arch.program_config(conf, smoke=o.program)
+    spec = Q.model_spec(conf)
     from repro.models import transformer as T
 
     shapes = jax.eval_shape(lambda k: T.init_model(k, cfg), jax.random.PRNGKey(0))
@@ -98,3 +98,23 @@ def test_weights_regenerate_bit_for_bit():
     np.testing.assert_array_equal(np.asarray(lw.top()["embed/tok"]), np.asarray(params["embed"]["tok"]))
     tok = np.asarray(params["embed"]["tok"])
     assert not tok[spec.vocab :].any()
+
+
+def test_backlog_fp8_control_fails_where_the_program_passes():
+    """The same through the backlog cell's whole run: the longest-context
+    request leads the sample, the control's picks fail, the program's
+    own tokens read no gap."""
+    import time
+
+    from bench import session
+
+    c = smoke.cell("pixelfly-longctx", "longctx", limit=SMOKE_LIMIT)
+    result, extra = session.run(
+        c, 2**31 + 99, 3.0, False, time.perf_counter(), test=smoke.options(c), control=True
+    )
+    assert not result["correct"]
+    assert result["compared"]["served_logit_gap_max"]["value"] > SMOKE_LIMIT
+    assert extra["program_gap"] <= 1e-3
+    longest = max((r for r in extra["record"]["window"].reqs if r.served),
+                  key=lambda r: (r.plen + len(r.served), r.idx))
+    assert extra["sample"][0] is longest

@@ -1,5 +1,6 @@
 """The open-loop generator and the benchmark's mix files."""
 
+import dataclasses
 import json
 import math
 
@@ -10,6 +11,8 @@ from bench import harness
 from bench.traffic import generator
 
 MIXES = sorted(p.stem for p in generator.TRAFFIC_DIR.glob("*.json"))
+POISSON = [m for m in MIXES if generator.load_mix(m).arrivals["kind"] == "poisson"]
+BACKLOG = [m for m in MIXES if generator.load_mix(m).arrivals["kind"] == "backlog"]
 SEEDS = (0, 7, 2**31 + 12345, 2**40 + 3)
 
 
@@ -26,7 +29,9 @@ def test_same_seed_same_schedule(mix):
     # another seed replays the schedule with other token ids
     c = generator.schedule(m, 2**33 + 2, 10.0, 151936)
     np.testing.assert_array_equal(a.due_s, c.due_s)
+    np.testing.assert_array_equal(a.prompt_len, c.prompt_len)
     np.testing.assert_array_equal(a.output_len, c.output_len)
+    assert a.in_setup == c.in_setup
     assert not all(np.array_equal(x, y) for x, y in zip(a.prompts, c.prompts))
 
 
@@ -53,7 +58,7 @@ def test_lengths_respect_clips_and_medians(mix):
     assert all(int(p.min()) >= 0 and int(p.max()) < 151936 for p in s.prompts)
 
 
-@pytest.mark.parametrize("mix", MIXES)
+@pytest.mark.parametrize("mix", POISSON)
 def test_arrivals(mix):
     m = generator.load_mix(mix)
     s = generator.schedule(m, 11, 30.0, 1000)
@@ -80,6 +85,28 @@ def test_warmed_buckets_cover_every_prompt(mix):
             chunk = 1 << (n.bit_length() - 1)
             assert (chunk, s) in warmed, (plen, n)
     assert m.prompt_len["max"] + m.output_len["min"] <= m.engine["max_len"]
+
+
+@pytest.mark.parametrize("mix", BACKLOG)
+def test_backlog_is_due_at_the_open(mix):
+    """Every request is due when the window opens, the first wave (one
+    request a slot) is submitted in set-up, and the count does not
+    depend on the window's length."""
+    m = generator.load_mix(mix)
+    a = generator.schedule(m, 2**31 + 5, 51.0, 151936)
+    b = generator.schedule(m, 9, 10.0, 151936)
+    assert len(a) == len(b) == m.arrivals["count"] > m.engine["max_slots"]
+    assert a.in_setup == m.engine["max_slots"]
+    assert not a.due_s.any()
+    np.testing.assert_array_equal(a.prompt_len, b.prompt_len)
+    np.testing.assert_array_equal(a.output_len, b.output_len)
+
+
+def test_unknown_arrival_kind_is_refused():
+    m = generator.load_mix(POISSON[0])
+    m = dataclasses.replace(m, arrivals={"kind": "no-such-kind"})
+    with pytest.raises(ValueError, match="no-such-kind"):
+        generator.schedule(m, 1, 10.0, 100)
 
 
 def test_quantiles_are_stratified():

@@ -94,7 +94,7 @@ def main() -> int:
     else:
         t0 = time.perf_counter()
         result, extra = session.run(cell, args.seed, args.seconds, True, t0, trace_dir=args.out)
-        emit(**{k: v for k, v in result.items() if k != "_compiles"})
+        emit(**{k: v for k, v in result.items() if not k.startswith("_")})
     return 0
 
 

@@ -114,40 +114,52 @@ def make_params(shapes, seed: int, vocab: int):
 
 
 class LayerWeights:
-    """Float32 copies of the leaves of one stacked layer, or of the
-    unstacked leaves, keyed by path (a layer's without the
+    """Float32 copies of the leaves of one layer of a stacked group, or of
+    the unstacked leaves, keyed by path (a layer's without the
     ``groups/<group>`` prefix). Values equal those ``make_params`` gives
     in their declared type, then upcast. One compiled builder serves
-    every layer."""
+    every layer of a group."""
 
     def __init__(self, shapes, seed: int, vocab: int):
         flat = jax.tree_util.tree_flatten_with_path(shapes)[0]
         paths = leaf_paths(shapes)
         self._key = base_key(seed)
-        self._layer = jax.jit(self._builder(flat, paths, vocab, stacked=True))
-        self._top = jax.jit(self._builder(flat, paths, vocab, stacked=False))
+        # the stacked groups, in the tree's order
+        self.groups = list(dict.fromkeys(p[1] for p in paths if _stacked(p)))
+        self._layer = {
+            g: jax.jit(self._builder(flat, paths, vocab, group=g)) for g in self.groups
+        }
+        self._top = jax.jit(self._builder(flat, paths, vocab, group=None))
 
     @staticmethod
-    def _builder(flat, paths, vocab, *, stacked: bool):
+    def _builder(flat, paths, vocab, *, group: str | None):
+        """Leaves of ``group``'s layers, or with None the unstacked ones;
+        each keeps the key of its index among all the tree's leaves."""
         picks = [
             (i, p, sds)
             for i, (p, (_, sds)) in enumerate(zip(paths, flat))
-            if _stacked(p) == stacked
+            if (p[1] == group if _stacked(p) else group is None)
         ]
 
         def build(key, li):
             out = {}
             for i, p, sds in picks:
                 k = jax.random.fold_in(key, i)
-                shape, name = (sds.shape[1:], p[2:]) if stacked else (sds.shape, p)
+                shape, name = (sds.shape[1:], p[2:]) if group else (sds.shape, p)
                 val = _one(jax.random.fold_in(k, li), p[-1], shape, sds.dtype, vocab)
                 out["/".join(name)] = val.astype(jnp.float32)
             return out
 
         return build
 
-    def layer(self, index: int) -> dict:
-        return self._layer(self._key, jnp.int32(index))
+    def layer(self, index: int, group: str | None = None) -> dict:
+        """Layer ``index`` of ``group``; without a group, of the tree's
+        only stacked group."""
+        if group is None:
+            if len(self.groups) != 1:
+                raise ValueError(f"name a group: the tree has {self.groups}")
+            group = self.groups[0]
+        return self._layer[group](self._key, jnp.int32(index))
 
     def top(self) -> dict:
         return self._top(self._key, jnp.int32(0))
