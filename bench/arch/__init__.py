@@ -22,5 +22,18 @@ A configuration file's ``architectures[0]`` selects its module by name:
     ``bsr_matmul(rows)``. A per-layer metric whose count the object
     lacks reads nothing.
 
+and may provide:
+
+``cache_shapes(cfg, n_pages, page, slots)``
+    The tree of ``ShapeDtypeStruct`` that the program's serving cache
+    holds for ``cfg`` (the program's configuration as the engine runs
+    it) with a pool of ``n_pages`` pages of ``page`` tokens and
+    ``slots`` sequences: the paged pools and any state kept per slot,
+    such as a state-space layer's. ``harness.size_pool`` lowers the
+    decode and prefill programs on it, so all of it is counted in what
+    each program needs; a page's bytes are its growth from one pool
+    size to another, which per-slot state leaves alone. Without it the
+    tree is the program's ``init_paged_cache(cfg, n_pages, page)``.
+
 Shared float32 and fp8 building blocks are in ``bench.arch.ops``.
 """

@@ -17,10 +17,15 @@ files and entries alone:
 - the traffic's ``arrivals.kind`` names ``bench/traffic/arrivals/<kind>.py``
   (see ``bench.traffic.generator``);
 - each per-layer metric is read by ``bench/metrics/<metric>.py``.
+
+A cell file may say ``"replicas": R``: the cell then runs
+``ReplicaRouter`` over R one-chip replicas of its ``chips`` in place of
+one ``Engine`` on all of them, each replica with the pool one chip gets.
 """
 
 from __future__ import annotations
 
+import concurrent.futures
 import contextlib
 import dataclasses
 import json
@@ -34,7 +39,7 @@ import numpy as np
 
 from bench import loader
 from bench.traffic import generator
-from bench.weights import tree_bytes
+from bench.weights import make_params, tree_bytes
 
 ROOT = Path(__file__).resolve().parents[1]
 # room on the chip beyond what the hungrier program needs: the runtime's
@@ -62,6 +67,7 @@ class Cell:
     end_to_end: list[dict]
     per_layer: list[dict]
     arch: ModuleType  # the configuration's architecture module (bench.arch)
+    replicas: int = 1  # engines behind a ReplicaRouter; 1 is one Engine
 
 
 def load_benchmark(root: Path = ROOT) -> dict:
@@ -79,15 +85,20 @@ def resolve_cell(bench: dict, name: str, root: Path = ROOT) -> Cell:
     w = wl[name]
     conf = {c["name"]: c for c in bench["configs"]}[w["config"]]
     config = json.loads((root / conf["file"]).read_text())
+    cell = json.loads((root / "bench" / "cells" / f"{name}.json").read_text())
+    chips, replicas = int(w["chips"]), int(cell.get("replicas", 1))
+    if replicas < 1 or chips % replicas:
+        raise ValueError(f"{name}: {replicas} replicas do not share {chips} chips evenly")
     return Cell(
         name=name,
-        chips=int(w["chips"]),
+        chips=chips,
         config=config,
         mix=generator.load_mix(w["traffic"], root / "bench" / "traffic"),
-        cell=json.loads((root / "bench" / "cells" / f"{name}.json").read_text()),
+        cell=cell,
         end_to_end=[m for m in bench["end_to_end"] if _in_cell(m, name)],
         per_layer=[m for m in bench["per_layer"] if _in_cell(m, name)],
         arch=arch_module(config, root),
+        replicas=replicas,
     )
 
 
@@ -138,15 +149,21 @@ def engine_config(mix: generator.Mix, n_pages: int):
     )
 
 
-def size_pool(cfg, mesh, param_shapes, mix, device) -> dict:
+def size_pool(cfg, mesh, param_shapes, mix, device, arch=None) -> dict:
     """Pages for the pool: what the chip holds after the parameters and
     the temporaries of the decode step and the largest prefill program,
-    as ``memory_analysis()`` of those programs reports them, so any
-    state the program keeps beside the pools is counted. Each program's
-    need is taken as affine in the pages: estimated from two small probe
-    pools, then the programs are compiled at the estimate and it is cut
-    until both fit. A page's bytes are read from the program's own pool
-    shapes (``init_paged_cache`` at the two probe sizes)."""
+    as ``memory_analysis()`` of those programs reports them. Each
+    program's need is taken as affine in the pages: estimated from two
+    small probe pools, then the programs are compiled at the estimate
+    and it is cut until both fit.
+
+    The serving cache the programs are lowered on is the architecture
+    module's ``cache_shapes(cfg, n_pages, page, slots)`` where ``arch``
+    gives it, else the program's ``init_paged_cache(cfg, n_pages,
+    page)``. Whatever that tree holds is counted in each program's need,
+    state sized by the slots as well as the pages. A page's bytes are
+    the difference of the tree between the two probe sizes, so state
+    that does not scale with the pages cancels out of them."""
     import jax
     import jax.numpy as jnp
 
@@ -173,10 +190,13 @@ def size_pool(cfg, mesh, param_shapes, mix, device) -> dict:
     )
 
     def pool_shapes(n_pages):
+        if hasattr(arch, "cache_shapes"):
+            return arch.cache_shapes(c, n_pages, page, slots)
         return jax.eval_shape(lambda: T.init_paged_cache(c, n_pages, page))
 
     def need(n_pages) -> list[int]:
-        """Bytes the device holds while each program runs."""
+        """Bytes the device holds while each program runs: the decode
+        step's, the largest prefill program's."""
         pool = pool_shapes(n_pages)
         with mesh:
             progs = (
@@ -197,7 +217,8 @@ def size_pool(cfg, mesh, param_shapes, mix, device) -> dict:
     page_bytes = (tree_bytes(pool_shapes(hi)) - tree_bytes(pool_shapes(lo))) // (hi - lo)
     # each program's need is affine in the pages: fit both, take the
     # tighter, then correct the estimate by what the compiler reports
-    lines = [(a, (b - a) / (hi - lo)) for a, b in zip(need(lo), need(hi))]
+    probes = {lo: need(lo), hi: need(hi)}
+    lines = [(a, (b - a) / (hi - lo)) for a, b in zip(probes[lo], probes[hi])]
     n = min(int(lo + (budget - a) // slope) for a, slope in lines)
     tries = []
     for _ in range(POOL_TRIES):
@@ -220,7 +241,8 @@ def size_pool(cfg, mesh, param_shapes, mix, device) -> dict:
         )
     else:
         raise RuntimeError(f"no pool size fits {budget} bytes: {tries}")
-    return {"n_pages": n, "page_bytes": page_bytes, "bytes_limit": limit, "param_bytes": params, "tries": tries}
+    return {"n_pages": n, "page_bytes": page_bytes, "bytes_limit": limit, "param_bytes": params, "tries": tries,
+            "probes": probes}
 
 
 def build_engine(cfg, mesh, params, mix, n_pages: int, paged_impl=None):
@@ -230,6 +252,78 @@ def build_engine(cfg, mesh, params, mix, n_pages: int, paged_impl=None):
         cfg, mesh, engine_cfg=engine_config(mix, n_pages), params=params,
         paged_impl=paged_impl,
     )
+
+
+def router_devices(devices) -> list:
+    """``devices`` in the order the router's replicas take them: JAX's
+    default device last. A one-device engine creates its pool on the
+    default device and moves it to its own with its first decode step,
+    in its constructor; so the default device holds one pool at a time
+    only where its own replica is built last."""
+    import jax
+
+    default = jax.devices()[0]
+    return [d for d in devices if d != default] + [d for d in devices if d == default]
+
+
+def build_router(cfg, devices, replicas: int, mix, n_pages: int, paged_impl=None):
+    """``ReplicaRouter`` over ``replicas`` disjoint slices of ``devices``
+    (in ``router_devices`` order): each replica an engine with a pool of
+    ``n_pages`` and its parameters placed as a one-chip cell's engine
+    places them (``fsdp``)."""
+    from repro.serving.router import ReplicaRouter
+
+    return ReplicaRouter(
+        cfg, replicas=replicas, tp=len(devices) // replicas, engine_cfg=engine_config(mix, n_pages),
+        strategy="fsdp", paged_impl=paged_impl, devices=router_devices(devices),
+    )
+
+
+def load_weights(router, param_shapes, seed: int, vocab: int) -> None:
+    """Put the seed's weights in every replica of ``router`` in place of
+    those the replica made for itself, as a checkpoint load would: made
+    on the replica's own chips (``make_params``), placed as its programs
+    expect. The replica's own weights are freed first."""
+    import jax
+
+    for eng in router.engines:
+        shardings = jax.tree.map(lambda a: a.sharding, eng.params)
+        eng.params = None
+        eng.params = make_params(param_shapes, seed, vocab, shardings=shardings)
+    jax.block_until_ready([eng.params for eng in router.engines])
+
+
+class Fleet:
+    """What the open loop drives: one ``Engine``, or a ``ReplicaRouter``
+    with its replicas' engines behind it. Requests go through the
+    server's ``submit`` and ``step``; each request's replica and its id
+    there are the router's placement of it."""
+
+    def __init__(self, server):
+        self.server = server
+        self.routed = hasattr(server, "engines")
+        self.engines = list(server.engines) if self.routed else [server]
+
+    def submit(self, prompt, max_new_tokens: int) -> tuple[int, int, int]:
+        """(uid, replica, the replica's own uid)."""
+        uid = self.server.submit(prompt, max_new_tokens)
+        if not self.routed:
+            return uid, 0, uid
+        replica, local = self.server._placed[uid]
+        return uid, replica, local
+
+    def step(self):
+        return self.server.step()
+
+    def running(self):
+        """(replica, the replica's uid, state) of every running sequence."""
+        return [(i, st.request.uid, st) for i, e in enumerate(self.engines) for st in e.scheduler.active()]
+
+    def waiting(self) -> int:
+        return sum(len(e.scheduler.waiting) for e in self.engines)
+
+    def idle(self) -> bool:
+        return not any(e.scheduler.active() or e.scheduler.waiting for e in self.engines)
 
 
 def warm(engine, mix) -> None:
@@ -245,6 +339,17 @@ def warm(engine, mix) -> None:
         if len(done) != n or engine.scheduler.active() or engine.scheduler.waiting:
             raise RuntimeError(f"warm-up of bucket ({n}, {s}) did not finish in one step")
     engine.reset_stats()
+
+
+def warm_fleet(fleet: Fleet, mix) -> None:
+    """``warm`` every replica, the replicas side by side, one thread each:
+    each loads or compiles programs for its own chips."""
+    if len(fleet.engines) == 1:
+        warm(fleet.engines[0], mix)
+        return
+    with concurrent.futures.ThreadPoolExecutor(len(fleet.engines)) as pool:
+        for f in [pool.submit(warm, e, mix) for e in fleet.engines]:
+            f.result()
 
 
 # ----------------------------------------------------------------------
@@ -266,6 +371,8 @@ class Req:
     finish_time: float | None = None
     failed: bool = False
     served: list | None = None  # token ids served by the close (finished or not)
+    replica: int = 0  # the replica that serves it
+    local: int = -1  # its uid in that replica's engine
 
     @property
     def count(self) -> int:
@@ -279,6 +386,11 @@ class Step:
     prefill_tokens: int = 0  # prompt tokens of requests admitted in the step
     prefills: list = dataclasses.field(default_factory=list)  # prompt lengths
     decode_positions: list = dataclasses.field(default_factory=list)
+    # behind a router: the same step's work on each replica that worked
+    parts: dict = dataclasses.field(default_factory=dict)
+
+    def on(self, replica: int) -> "Step":
+        return self.parts.setdefault(replica, Step(self.start, self.end))
 
 
 @dataclasses.dataclass
@@ -294,8 +406,17 @@ class Window:
     def seconds(self) -> float:
         return self.close - self.open
 
+    def on(self, replica: int) -> "Window":
+        """The window as one replica saw it: every request, and each
+        step's work on that replica alone (the whole step where the
+        steps were not split by replica)."""
+        if not any(s.parts for s in self.steps):
+            return self
+        steps = [s.parts.get(replica, Step(s.start, s.end)) for s in self.steps]
+        return dataclasses.replace(self, steps=steps)
 
-def admit_in_setup(engine, sched: generator.Schedule) -> list[Req]:
+
+def admit_in_setup(fleet: Fleet, sched: generator.Schedule) -> list[Req]:
     """Submit the schedule's first ``sched.in_setup`` requests and take
     one step, which admits as many of them as the pool holds (an
     admission pass plans every waiting request its budget fits) and
@@ -307,25 +428,28 @@ def admit_in_setup(engine, sched: generator.Schedule) -> list[Req]:
     reqs = []
     for i in range(sched.in_setup):
         r = Req(i, math.nan, int(sched.prompt_len[i]), int(sched.output_len[i]))
-        r.uid = engine.submit(sched.prompts[i], r.olen)
+        r.uid, r.replica, r.local = fleet.submit(sched.prompts[i], r.olen)
         r.submit = clock()
         reqs.append(r)
-    fins = engine.step()
+    by_local = {(r.replica, r.local): r for r in reqs}
+    fins = fleet.step()
     t = clock()
-    if fins or engine.scheduler.num_free_slots == engine.ecfg.max_slots:
+    running = fleet.running()
+    if fins or not running:
         raise RuntimeError(f"set-up admitted {len(fins)} finished and no running request")
-    counts = {st.request.uid: len(st.generated) for st in engine.scheduler.active()}
-    for r in reqs:
-        r.times = [t] * counts.get(r.uid, 0)
+    for replica, local, st in running:
+        r = by_local[replica, local]
+        r.times = [t] * len(st.generated)
     return reqs
 
 
-def open_loop(engine, sched: generator.Schedule, seconds: float, *, t_open: float | None = None,
+def open_loop(fleet: Fleet, sched: generator.Schedule, seconds: float, *, t_open: float | None = None,
               annotate: bool = False, hooks=None, started: list[Req] | None = None) -> Window:
     """Submit each request when due, step while there is work, sleep
-    only while the engine is idle. Tokens are stamped at the end of the
-    step that produced them. ``started`` are the schedule's first
-    requests, submitted during set-up (``admit_in_setup``)."""
+    only while every replica is idle. Tokens are stamped at the end of
+    the step that produced them. ``started`` are the schedule's first
+    requests, submitted during set-up (``admit_in_setup``). Behind a
+    router, each step's work is also kept per replica."""
     import jax
 
     from repro.analysis.guards import compile_events_total
@@ -337,6 +461,7 @@ def open_loop(engine, sched: generator.Schedule, seconds: float, *, t_open: floa
     close = t_open + seconds
     reqs: list[Req] = list(started or [])
     by_uid: dict[int, Req] = {r.uid: r for r in reqs}
+    by_local: dict[tuple[int, int], Req] = {(r.replica, r.local): r for r in reqs}
     pending = [
         Req(i, t_open + float(d), int(p), int(o))
         for i, (d, p, o) in enumerate(zip(sched.due_s, sched.prompt_len, sched.output_len))
@@ -358,53 +483,56 @@ def open_loop(engine, sched: generator.Schedule, seconds: float, *, t_open: floa
             with span("submit"):
                 while nxt < len(pending) and pending[nxt].due <= now:
                     r = pending[nxt]
-                    r.uid = engine.submit(prompts[nxt], r.olen)
+                    r.uid, r.replica, r.local = fleet.submit(prompts[nxt], r.olen)
                     r.submit = clock()
                     by_uid[r.uid] = r
+                    by_local[r.replica, r.local] = r
                     reqs.append(r)
                     nxt += 1
-        if not engine.scheduler.active() and not engine.scheduler.waiting:
+        if fleet.idle():
             wake = pending[nxt].due if nxt < len(pending) else close
             with span("wait_for_arrival"):
                 time.sleep(max(0.0, min(wake, close) - clock()))
             continue
-        before = {st.request.uid: st.pos for st in engine.scheduler.active()}
+        before = {by_local[i, u].idx: st.pos for i, u, st in fleet.running()}
         t0 = clock()
         with span("step", step=len(steps)):
-            fins = engine.step()
+            fins = fleet.step()
         t1 = clock()
         step = Step(t0, t1)
-        counts = {st.request.uid: len(st.generated) for st in engine.scheduler.active()}
+        counts = {by_local[i, u].idx: (by_local[i, u], len(st.generated)) for i, u, st in fleet.running()}
         for f in fins:
-            counts[f.uid] = len(f.tokens)
             r = by_uid[f.uid]
+            counts[r.idx] = (r, len(f.tokens))
             r.finished, r.finish_time = f, t1
             r.failed = f.finish_reason in ("rejected", "capacity") or f.preemptions > 0
-        for uid, n in counts.items():
-            r = by_uid[uid]
+        for idx, (r, n) in counts.items():
             new = n - r.count
             if new <= 0:
                 continue
+            where = (step, step.on(r.replica)) if fleet.routed else (step,)
             if r.count == 0:
                 r.first_step = len(steps)
-                step.prefills.append(r.plen)
-                step.prefill_tokens += r.plen
+                for s in where:
+                    s.prefills.append(r.plen)
+                    s.prefill_tokens += r.plen
                 new -= 1
                 r.times.append(t1)
             if new > 0:
                 # decode tokens: the request's slot sat at ``pos`` before
                 # the step, or at ``plen`` when admitted in this step
-                p0 = before.get(uid, r.plen)
-                step.decode_positions.extend(range(p0, p0 + new))
+                p0 = before.get(idx, r.plen)
+                for s in where:
+                    s.decode_positions.extend(range(p0, p0 + new))
                 r.times.extend([t1] * new)
         steps.append(step)
     compiles = compile_events_total() - c0
     for r in reqs:
         if r.finished is not None:
             r.served = [int(t) for t in r.finished.tokens]
-    for st in engine.scheduler.active():
-        by_uid[st.request.uid].served = [int(t) for t in st.generated]
-    return Window(t_open, close, reqs, steps, compiles, len(engine.scheduler.waiting))
+    for i, u, st in fleet.running():
+        by_local[i, u].served = [int(t) for t in st.generated]
+    return Window(t_open, close, reqs, steps, compiles, fleet.waiting())
 
 
 # ----------------------------------------------------------------------
@@ -470,8 +598,9 @@ def end_to_end(win: Window, setup_s: float, names: list[str]) -> dict:
 def pick_sample(win: Window, seed: int) -> list[Req]:
     """Requests for the reference: among those that served tokens by the
     close (finished, or still decoding), the one with the longest
-    context, then others in an order drawn from the seed, up to
-    ``SAMPLE_TOKENS`` served tokens."""
+    context, then others in an order drawn from the seed: first the
+    first in that order from each replica not yet in the sample, then
+    the rest up to ``SAMPLE_TOKENS`` served tokens."""
     done = [r for r in win.reqs if r.served and not r.failed]
     if not done:
         return []
@@ -479,9 +608,17 @@ def pick_sample(win: Window, seed: int) -> list[Req]:
     rest = [r for r in done if r is not longest]
     order = generator.seed_rng(seed, 5).permutation(len(rest))
     out, n = [longest], len(longest.served)
+    replicas = {longest.replica}
+    for i in order:
+        if rest[i].replica not in replicas:
+            replicas.add(rest[i].replica)
+            out.append(rest[i])
+            n += len(rest[i].served)
     for i in order:
         if n >= SAMPLE_TOKENS or len(out) >= SAMPLE_MAX_REQUESTS:
             break
+        if any(rest[i] is r for r in out):
+            continue
         out.append(rest[i])
         n += len(rest[i].served)
     return out

@@ -39,8 +39,8 @@ class TestOptions:
     program: dict  # ModelConfig fields for the same (e.g. dtype, blocks)
     n_pages: int
     paged_impl: str = "interpret"
-    # faults planted in the timed path: called with the engine after
-    # set-up, before the window opens
+    # faults planted in the timed path: called with the engine (or the
+    # router) after set-up, before the window opens
     fault: Callable | None = None
 
 
@@ -110,9 +110,15 @@ def run(cell: harness.Cell, seed: int, seconds: float, trace: bool, t_start: flo
     vocab = conf["vocab_size"]
     mix = cell.mix
     devs = jax.devices()[: cell.chips]
+    if cell.replicas > 1:
+        devs = harness.router_devices(devs)
     dev = devs[0]
     peaks = peaks_for(dev.device_kind) if not test else None
-    mesh = make_tp_mesh(cell.chips)
+    # one engine on all the cell's chips, or one per slice of them
+    # behind a router; the pool is sized on the first slice
+    tp = cell.chips // cell.replicas
+    mesh = make_tp_mesh(tp, devs[:tp])
+    paged_impl = test.paged_impl if test else None
 
     # ---- set-up: weights, pool, engine, warm-up ----------------------
     marks = [("start", t_start)]
@@ -121,35 +127,44 @@ def run(cell: harness.Cell, seed: int, seconds: float, trace: bool, t_start: flo
         marks.append((name, time.perf_counter()))
 
     param_shapes = jax.eval_shape(lambda k: T.init_model(k, cfg), jax.random.PRNGKey(0))
-    params = make_params(param_shapes, seed, vocab)
-    jax.block_until_ready(params)
-    mark("weights")
+    if cell.replicas == 1:
+        params = make_params(param_shapes, seed, vocab)
+        jax.block_until_ready(params)
+        mark("weights")
     if test:
         pool = {"n_pages": test.n_pages}
     else:
-        pool = harness.size_pool(cfg, mesh, param_shapes, mix, dev)
+        pool = harness.size_pool(cfg, mesh, param_shapes, mix, dev, arch)
     mark("pool")
-    engine = harness.build_engine(
-        cfg, mesh, params, mix, pool["n_pages"], paged_impl=test.paged_impl if test else None
-    )
-    if not test and engine.paged_impl != "pallas":
-        raise RuntimeError(f"paged decode took {engine.paged_impl!r}, not the Pallas kernel")
-    del params
-    mark("engine")
-    harness.warm(engine, mix)
+    if cell.replicas == 1:
+        server = harness.build_engine(cfg, mesh, params, mix, pool["n_pages"], paged_impl=paged_impl)
+        del params
+        mark("engine")
+    else:
+        server = harness.build_router(cfg, devs, cell.replicas, mix, pool["n_pages"], paged_impl=paged_impl)
+        mark("router")
+        harness.load_weights(server, param_shapes, seed, vocab)
+        mark("weights")
+    fleet = harness.Fleet(server)
+    taken = {e.paged_impl for e in fleet.engines}
+    if not test and taken != {"pallas"}:
+        raise RuntimeError(f"paged decode took {sorted(taken)}, not the Pallas kernel")
+    harness.warm_fleet(fleet, mix)
     mark("warm")
     sched = generator.schedule(mix, seed, seconds, vocab, rate=rate)
-    started = harness.admit_in_setup(engine, sched)
+    started = harness.admit_in_setup(fleet, sched)
     if started:
         mark("first wave")
-    engine.reset_stats()  # the counters read are the window's
+    server.reset_stats()  # the counters read are the window's
     if test and test.fault:
-        test.fault(engine)
+        test.fault(server)
     setup_s = time.perf_counter() - t_start
     phases = ", ".join(f"{b[0]} {b[1] - a[1]:.2f} s" for a, b in zip(marks, marks[1:]))
     note = f"pool {pool['n_pages']} pages"
     if "page_bytes" in pool:
         note += f" of {pool['page_bytes']} B"
+    if cell.replicas > 1:
+        note += f" in each of {cell.replicas} replicas"
     if started:
         note += f"; {sum(r.count > 0 for r in started)} of {len(started)} admitted in set-up"
     print(f"set-up: {phases}; {note}", file=sys.stderr, flush=True)
@@ -158,20 +173,24 @@ def run(cell: harness.Cell, seed: int, seconds: float, trace: bool, t_start: flo
     t_open = time.perf_counter()
     tracer = _Tracer(t_open, seconds, trace_dir) if trace else None
     win = harness.open_loop(
-        engine, sched, seconds, t_open=t_open, annotate=trace,
+        fleet, sched, seconds, t_open=t_open, annotate=trace,
         hooks={"tick": tracer.tick} if tracer else None, started=started,
     )
     if tracer:
         tracer.finish()
     peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0) for d in devs)
-    st = engine.stats
+    stats = [e.stats for e in fleet.engines]
+    first = fleet.engines[0]
     counters = {
-        "decode_steps": st.decode_steps,
-        "decode_tokens": st.generated - st.prefill_requests,
-        "max_slots": engine.ecfg.max_slots,
-        "kv_pool_tokens": (engine.kv.n_pages - 1) * engine.kv.page,
+        "decode_steps": sum(st.decode_steps for st in stats),
+        "decode_tokens": sum(st.generated - st.prefill_requests for st in stats),
+        "max_slots": first.ecfg.max_slots,
+        # one replica's pool: every replica has the same
+        "kv_pool_tokens": (first.kv.n_pages - 1) * first.kv.page,
+        # tokens each replica emitted in the window
+        "replica_tokens": [st.generated for st in stats],
     }
-    del engine, st
+    del server, fleet, first, stats
     gc.collect()
 
     # ---- correctness -----------------------------------------------------
@@ -217,12 +236,21 @@ def run(cell: harness.Cell, seed: int, seconds: float, trace: bool, t_start: flo
         red = trace_reduce.reduce_dir(tracer.dir, tracer.t0, tracer.t1)
         if not tracer.keep:
             shutil.rmtree(tracer.dir, ignore_errors=True)
-        device["busy_s"] = red.busy_s
+        # busy seconds averaged over the cell's chips; the per-layer
+        # metrics read the busiest chip, and a metric read from the
+        # trace reads it against that chip's own work
+        busy = [red.busy_by_device.get(d.id, 0.0) for d in devs] if red.busy_by_device else [red.busy_s]
+        device["busy_s"] = sum(busy) / len(busy)
         device["window_s"] = red.window_s
         breakdown = {"device_ops": red.top_ops(10), "idle_gaps": red.idle_gaps(10)}
+        replica_of = {d.id: i // tp for i, d in enumerate(devs)}
+        traced = {**record, "window": win.on(replica_of[red.device])} if red.device in replica_of else record
+        if cell.replicas > 1:
+            print(f"traced chip: device {red.device} of {sorted(replica_of)}, replica "
+                  f"{replica_of.get(red.device)}; busy s by device {red.busy_by_device}", file=sys.stderr)
         readers = _readers(cell)
         for m in cell.per_layer:
-            val = readers[m["name"]](record, red)
+            val = readers[m["name"]](traced if m["source"] == "device_trace" else record, red)
             if val is not None:
                 metrics[m["name"]] = {"value": val, "unit": m["unit"]}
     else:

@@ -267,7 +267,7 @@ def read_file(path: str) -> EngineTrace:
         data = f.read()
     pd = ProfileData.from_serialized_xspace(data)
     scopes = _op_scopes(data)
-    raw_ops, raw_mods = _device_events(pd)
+    raw_ops, raw_mods, _, _ = _device_events(pd)
     mods = sorted(raw_mods)
     runs, pids = [], []
     for a, dur, name in mods:

@@ -3,7 +3,7 @@ metrics read.
 
 What a trace offers, and what is read from it:
 
-- device operations: the events of a TPU device plane's ``XLA Ops``
+- device operations: the events of the busiest TPU device plane's ``XLA Ops``
   line, each inside one execution of a compiled program (its ``XLA
   Modules`` line). On a host without an accelerator, the events of the
   host plane that carry an ``hlo_module`` stat stand in (tests only).
@@ -65,6 +65,10 @@ class Reduced:
     steps: dict[int, StepDevice]
     ops: list[Op]
     gaps: list[tuple[str, float]]  # (host span the gap falls in, seconds)
+    # the device number of the plane read (None on a host without an
+    # accelerator), and the busy seconds of every device plane
+    device: int | None = None
+    busy_by_device: dict[int, float] = dataclasses.field(default_factory=dict)
 
     def top_ops(self, n: int) -> list[list]:
         """The device operations that took most time, by kind; loops and
@@ -107,10 +111,17 @@ def _union(intervals: list[tuple[float, float]]) -> list[tuple[float, float]]:
     return [(a, b) for a, b in out]
 
 
+def _busy_ns(ops) -> float:
+    return sum(b - a for a, b in _union([(o[0], o[0] + o[1]) for o in ops]))
+
+
 def _device_events(pd):
-    """(ops, modules): raw (start, dur, text, name) tuples of the busiest
-    device plane's operations and program executions."""
-    best = None
+    """(ops, modules, device, busy): raw (start, dur, text, name) tuples of
+    the busiest device plane's operations and program executions, that
+    plane's device number (``/device:TPU:<n>``), and the busy seconds of
+    every device plane by its number. On a host without an accelerator
+    the device is None and the busy seconds are empty."""
+    best, busy_by_device = None, {}
     for plane in pd.planes:
         if not plane.name.startswith("/device:"):
             continue
@@ -124,11 +135,15 @@ def _device_events(pd):
         mods = []
         for ev in lines.get("XLA Modules", None).events if "XLA Modules" in lines else []:
             mods.append((ev.start_ns, ev.duration_ns, ev.name))
+        m = re.search(r":(\d+)$", plane.name)
+        device = int(m.group(1)) if m else None
+        if device is not None:
+            busy_by_device[device] = _busy_ns(ops) / 1e9
         busy = sum(o[1] for o in ops)
         if best is None or busy > best[2]:
-            best = (ops, mods, busy)
+            best = (ops, mods, busy, device)
     if best is not None:
-        return best[0], best[1]
+        return best[0], best[1], best[3], busy_by_device
     # no device plane: the host's compiled-program events (CPU tests)
     ops, mods = [], {}
     for plane in pd.planes:
@@ -142,7 +157,7 @@ def _device_events(pd):
                     key = (st["hlo_module"], st.get("run_id"))
                     a, b = mods.get(key, (ev.start_ns, ev.start_ns + ev.duration_ns))
                     mods[key] = (min(a, ev.start_ns), max(b, ev.start_ns + ev.duration_ns))
-    return ops, [(a, b - a, str(k[0])) for k, (a, b) in mods.items()]
+    return ops, [(a, b - a, str(k[0])) for k, (a, b) in mods.items()], None, {}
 
 
 def _host_spans(pd) -> list[tuple[str, float, float, dict]]:
@@ -162,7 +177,7 @@ def reduce_file(path: str, window_s: float | None = None) -> Reduced:
     from jax.profiler import ProfileData
 
     pd = ProfileData.from_file(path)
-    raw_ops, raw_mods = _device_events(pd)
+    raw_ops, raw_mods, device, busy_by_device = _device_events(pd)
     mods = sorted(raw_mods)
     starts = [m[0] for m in mods]
 
@@ -229,7 +244,8 @@ def reduce_file(path: str, window_s: float | None = None) -> Reduced:
         mid = (b0 + a1) / 2
         name = next((n for a, b, n in hs if a <= mid <= b), "harness")
         gaps.append((name, (a1 - b0) / 1e9))
-    return Reduced(window_s=window_s, busy_s=busy_s, steps=steps, ops=ops, gaps=gaps)
+    return Reduced(window_s=window_s, busy_s=busy_s, steps=steps, ops=ops, gaps=gaps,
+                   device=device, busy_by_device=busy_by_device)
 
 
 def reduce_dir(path: str, t0: float | None = None, t1: float | None = None) -> Reduced:

@@ -82,8 +82,9 @@ def _one(key, name: str, shape, dtype, vocab: int):
     return val.astype(dtype)
 
 
-def make_params(shapes, seed: int, vocab: int):
-    """The whole tree on the default device in one jitted call.
+def make_params(shapes, seed: int, vocab: int, shardings=None):
+    """The whole tree in one jitted call, on the default device or, with
+    ``shardings`` (a matching tree), made where those place each leaf.
     ``shapes`` is the program's tree of ``ShapeDtypeStruct``."""
     flat, treedef = jax.tree_util.tree_flatten_with_path(shapes)
     paths = leaf_paths(shapes)
@@ -110,7 +111,8 @@ def make_params(shapes, seed: int, vocab: int):
                 )
         return jax.tree_util.tree_unflatten(treedef, out)
 
-    return jax.jit(build)(base_key(seed))
+    placed = {} if shardings is None else {"out_shardings": shardings}
+    return jax.jit(build, **placed)(base_key(seed))
 
 
 class LayerWeights:
