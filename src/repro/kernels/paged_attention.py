@@ -13,20 +13,26 @@ allows": page-table indirection and the O(b·log n) sparse schedule fused
 into one pass over VMEM-resident accumulators.
 
 Layout and masking:
-  - grid ``(B, Hk, w)`` — slots x kv-heads x schedule slots; the
-    schedule axis is sequential so the online-softmax statistics
-    (m, l) and the output accumulator stay resident in VMEM.
+  - grid ``(B, w)`` — slots x schedule slots, with no kv-head axis: every
+    kv head of a slot reads the same page at the same schedule slot, so
+    one grid step visits one page for all heads. The schedule axis is
+    sequential so the online-softmax statistics (m, l) and the output
+    accumulator stay resident in VMEM, each ``(Hk*G, ·)``: every head's
+    query rows stacked.
   - q is pre-grouped ``(B, Hk, G, D)`` (GQA: G query heads share one
-    kv head); each grid step contracts the (G, D) query block with one
-    (page, D) pool page.
+    kv head) and read as the slot's ``(Hk*G, D)`` block; each grid step
+    contracts it with the page's ``(Hk*page, D)`` keys in one 2-D
+    product and keeps head h's diagonal block (its G rows against its
+    own page columns), so the softmax runs on the stacked ``(Hk*G,
+    page)`` scores; the probabilities go back block-diagonal for one
+    product with the stacked values.
   - the pools are the model's stacked, head-major ``(L, n_pages, Hk,
     page, D)`` buffers, read in place: the layer index is
     scalar-prefetched with the schedule, so one grid step's K/V block
-    is the whole ``(page, D)`` tile at ``(layer, phys, h)`` and the
+    is the whole ``(Hk, page, D)`` slab at ``(layer, phys)`` and the
     layer scan never slices a per-layer pool out for the kernel. A
-    page-major pool ``(..., page, Hk, D)`` would need a size-1 block on
-    its Hk axis, which the TPU compiler refuses (the last two block dims
-    must be multiples of (8, 128) or the full array dims).
+    page-major pool ``(..., page, Hk, D)`` would interleave the heads
+    within each page row.
   - ``logical`` carries the *logical* block id of every schedule slot:
     key position ``logical * page + offset`` is masked against the
     slot's current position, which also neutralizes the shared trash
@@ -66,11 +72,12 @@ def _kernel(
     acc_ref,
     *,
     w: int,
+    g: int,
     page: int,
     sm_scale: float,
 ):
     b = pl.program_id(0)
-    t = pl.program_id(2)
+    t = pl.program_id(1)
 
     @pl.when(t == 0)
     def _init():
@@ -80,17 +87,25 @@ def _kernel(
 
     @pl.when(keep_ref[b, t] == 1)
     def _visit():
-        q = q_ref[0, 0]  # (G, D)
-        k = k_ref[0, 0]  # (page, D)
-        v = v_ref[0, 0]
-        s = (
-            jnp.dot(q, k.T, preferred_element_type=jnp.float32) * sm_scale
-        )  # (G, page)
+        _, hk, _, d = k_ref.shape
+        q = q_ref[0]  # (Hk*G, D): every head's query rows, stacked
+        k = k_ref[0].reshape(hk * page, d)  # every head's page, stacked
+        v = v_ref[0].reshape(hk * page, d)
+        # one product over all heads; head h's scores are the diagonal
+        # block of rows [h*G, (h+1)*G) and columns [h*page, (h+1)*page)
+        s_all = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        )  # (Hk*G, Hk*page)
+        row = jax.lax.broadcasted_iota(jnp.int32, (hk * g, page), 0)
+        s = s_all[:, (hk - 1) * page :]
+        for h in range(hk - 2, -1, -1):
+            s = jnp.where(row < (h + 1) * g, s_all[:, h * page : (h + 1) * page], s)
+        s = s * sm_scale  # (Hk*G, page)
         kpos = logical_ref[b, t] * page + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 1
         )
         s = jnp.where(kpos <= pos_ref[b], s, _NEG_INF)
-        m_prev = m_ref[:, :1]  # (G, 1)
+        m_prev = m_ref[:, :1]  # (Hk*G, 1)
         m_cur = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
         still_masked = m_cur <= _NEG_INF / 2
         alpha = jnp.where(still_masked, 1.0, jnp.exp(m_prev - m_cur))
@@ -99,17 +114,24 @@ def _kernel(
         l_ref[...] = jnp.broadcast_to(
             l_prev * alpha + p.sum(axis=-1, keepdims=True), l_ref.shape
         )
+        # each head's probabilities in its own column block, zero elsewhere,
+        # so one product against the stacked V gives every head's output
+        p_blocks = jnp.concatenate(
+            [
+                jnp.where((row >= h * g) & (row < (h + 1) * g), p, 0.0)
+                for h in range(hk)
+            ],
+            axis=1,
+        ).astype(v.dtype)  # (Hk*G, Hk*page)
         acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32
+            p_blocks, v, preferred_element_type=jnp.float32
         )
         m_ref[...] = jnp.broadcast_to(m_cur, m_ref.shape)
 
     @pl.when(t == w - 1)
     def _flush():
         l = l_ref[:, :1]
-        o_ref[0, 0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(
-            o_ref.dtype
-        )
+        o_ref[0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
@@ -141,41 +163,42 @@ def paged_decode_attention_pallas(
     if phys.shape != logical.shape or phys.shape != keep.shape:
         raise ValueError("schedule arrays must share shape (B, w)")
     w = phys.shape[1]
+    rows = hk * g
 
-    grid = (b, hk, w)
+    grid = (b, w)
 
-    def q_map(bi, hi, t, layer_ref, phys_ref, logical_ref, keep_ref, pos_ref):
+    def q_map(bi, t, layer_ref, phys_ref, logical_ref, keep_ref, pos_ref):
         del t, layer_ref, phys_ref, logical_ref, keep_ref, pos_ref
-        return (bi, hi, 0, 0)
+        return (bi, 0, 0)
 
-    def kv_map(bi, hi, t, layer_ref, phys_ref, logical_ref, keep_ref, pos_ref):
+    def kv_map(bi, t, layer_ref, phys_ref, logical_ref, keep_ref, pos_ref):
         del logical_ref, keep_ref, pos_ref
-        return (layer_ref[0], phys_ref[bi, t], hi, 0, 0)
+        return (layer_ref[0], phys_ref[bi, t], 0, 0, 0)
 
-    kernel = functools.partial(_kernel, w=w, page=page, sm_scale=sm_scale)
-    return pl.pallas_call(
+    kernel = functools.partial(_kernel, w=w, g=g, page=page, sm_scale=sm_scale)
+    out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, 1, g, d), q_map),
-                pl.BlockSpec((pl.squeezed, 1, 1, page, d), kv_map),
-                pl.BlockSpec((pl.squeezed, 1, 1, page, d), kv_map),
+                pl.BlockSpec((1, rows, d), q_map),
+                pl.BlockSpec((pl.squeezed, 1, hk, page, d), kv_map),
+                pl.BlockSpec((pl.squeezed, 1, hk, page, d), kv_map),
             ],
-            out_specs=pl.BlockSpec((1, 1, g, d), q_map),
+            out_specs=pl.BlockSpec((1, rows, d), q_map),
             scratch_shapes=[
-                pltpu.VMEM((g, 128), jnp.float32),
-                pltpu.VMEM((g, 128), jnp.float32),
-                pltpu.VMEM((g, d), jnp.float32),
+                pltpu.VMEM((rows, 128), jnp.float32),
+                pltpu.VMEM((rows, 128), jnp.float32),
+                pltpu.VMEM((rows, d), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, rows, d), q.dtype),
         interpret=interpret,
         # the kernel's name in a profiler trace
         name="paged_decode_attention_pallas",
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            dimension_semantics=("parallel", "arbitrary"),
         ),
     )(
         jnp.asarray(layer, jnp.int32).reshape(1),
@@ -183,7 +206,8 @@ def paged_decode_attention_pallas(
         logical.astype(jnp.int32),
         keep.astype(jnp.int32),
         pos.astype(jnp.int32),
-        q,
+        q.reshape(b, rows, d),
         k_pages,
         v_pages,
     )
+    return out.reshape(b, hk, g, d)

@@ -12,9 +12,12 @@ layer, as the layer scan passes them, so a read of the wrong layer
 shows too.
 """
 
+import functools
+
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from repro.models import layers as L
@@ -23,34 +26,49 @@ PAGE, PPS, D = 8, 4, 16  # page size, pages per slot, head dim
 LAYERS = 3  # stacked pool depth; the tests read layers 0 and 2
 ATOL = {jnp.float32: 1e-5, jnp.bfloat16: 1e-2}
 
+# (kv heads, head dim, page, pages per slot) of the parity cases: the
+# suite's small geometry, and qwen3-1.7b's head shape (8 kv heads of 128,
+# 128-token pages), where the kernel's blocks hold (8, 128, 128) slabs
+SMALL = (2, D, PAGE, PPS)
+QWEN3_1_7B = (8, 128, 128, 3)
+PARITY_CASES = [
+    pytest.param(dtype, g, layer, SMALL, id=f"{dtype.__name__}-{g}-{layer}")
+    for dtype in (jnp.float32, jnp.bfloat16)
+    for g in (1, 2, 4)
+    for layer in (0, 2)
+] + [
+    pytest.param(dtype, 2, 1, QWEN3_1_7B, id=f"qwen3-1.7b-{dtype.__name__}")
+    for dtype in (jnp.float32, jnp.bfloat16)
+]
+
 
 def _scenario(b, hk, d, *, seed=0, dtype=jnp.float32, trash_slot=True,
-              partial_slot=True):
+              partial_slot=True, page=PAGE, pps=PPS):
     """Random stacked pools + page table with the parity suite's edge
     cases: ragged positions (partial last pages), a partially-allocated
     row, and an idle slot whose row is all trash page."""
     rng = np.random.default_rng(seed)
-    n_pages = b * PPS + 1
+    n_pages = b * pps + 1
     # head-major pools stacked over layers, each layer its own contents
-    k = rng.standard_normal((LAYERS, n_pages, hk, PAGE, d))
-    v = rng.standard_normal((LAYERS, n_pages, hk, PAGE, d))
+    k = rng.standard_normal((LAYERS, n_pages, hk, page, d))
+    v = rng.standard_normal((LAYERS, n_pages, hk, page, d))
     # poison the trash page: an unmasked read of page 0 shows up as a
     # huge output delta instead of hiding inside the tolerance
     k[:, 0] = 1e4
     v[:, 0] = -1e4
     perm = rng.permutation(np.arange(1, n_pages))
-    table = np.zeros((b, PPS), np.int32)
+    table = np.zeros((b, pps), np.int32)
     pos = np.zeros((b,), np.int32)
     nxt = 0
     for s in range(b):
         if partial_slot and s == b - 1 and b > 1:
             n_alloc = 1  # partially-allocated row, trash tail
         else:
-            n_alloc = PPS
+            n_alloc = pps
         table[s, :n_alloc] = perm[nxt:nxt + n_alloc]
         nxt += n_alloc
         # ragged: land mid-page so the last page is partially filled
-        pos[s] = int(rng.integers(0, n_alloc * PAGE))
+        pos[s] = int(rng.integers(0, n_alloc * page))
     if trash_slot and b > 2:
         table[1] = 0  # idle slot: all-trash row, position 0
         pos[1] = 0
@@ -86,35 +104,73 @@ def _run(fn, dtype):
     )
 
 
-@pytest.mark.parametrize("layer", [0, 2])
-@pytest.mark.parametrize("g", [1, 2, 4])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_dense_paged_parity(g, dtype, layer):
-    b, hk = 4, 2
-    k, v, table, pos, rng = _scenario(b, hk, D, dtype=dtype)
-    q = _q(rng, b, hk, g, D, dtype)
+@pytest.mark.parametrize("dtype, g, layer, geom", PARITY_CASES)
+def test_dense_paged_parity(g, dtype, layer, geom):
+    b, (hk, d, page, pps) = 4, geom
+    k, v, table, pos, rng = _scenario(b, hk, d, dtype=dtype, page=page, pps=pps)
+    q = _q(rng, b, hk, g, d, dtype)
     _run(
         lambda impl: L.paged_decode_attention_jnp(
-            q, k, v, layer, table, pos, sm_scale=D ** -0.5, impl=impl
+            q, k, v, layer, table, pos, sm_scale=d ** -0.5, impl=impl
         ),
         dtype,
     )
 
 
-@pytest.mark.parametrize("layer", [0, 2])
-@pytest.mark.parametrize("g", [1, 2, 4])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_sparse_paged_parity(g, dtype, layer):
-    b, hk = 4, 2
-    k, v, table, pos, rng = _scenario(b, hk, D, dtype=dtype)
-    q = _q(rng, b, hk, g, D, dtype)
+@pytest.mark.parametrize("dtype, g, layer, geom", PARITY_CASES)
+def test_sparse_paged_parity(g, dtype, layer, geom):
+    b, (hk, d, page, pps) = 4, geom
+    k, v, table, pos, rng = _scenario(b, hk, d, dtype=dtype, page=page, pps=pps)
+    q = _q(rng, b, hk, g, d, dtype)
     _run(
         lambda impl: L.paged_sparse_decode_attention_jnp(
-            q, k, v, layer, table, pos, sm_scale=D ** -0.5,
+            q, k, v, layer, table, pos, sm_scale=d ** -0.5,
             local_blocks=2, global_blocks=1, impl=impl,
         ),
         dtype,
     )
+
+
+def test_kernel_grid_steps_each_page_once_for_all_heads():
+    """The kernel's grid is (slots, schedule pages) with no kv-head axis:
+    one grid step reads one page of every head, its K/V block the whole
+    (Hk, page, D) slab at (layer, phys)."""
+    from repro.kernels.paged_attention import paged_decode_attention_pallas
+
+    b, w = 3, 5
+    hk, d, page, _ = QWEN3_1_7B
+    pool = jax.ShapeDtypeStruct((LAYERS, b * w + 1, hk, page, d), jnp.bfloat16)
+    sched = jax.ShapeDtypeStruct((b, w), jnp.int32)
+    jaxpr = jax.make_jaxpr(
+        functools.partial(paged_decode_attention_pallas, sm_scale=d ** -0.5)
+    )(
+        jax.ShapeDtypeStruct((b, hk, 2, d), jnp.bfloat16), pool, pool,
+        jax.ShapeDtypeStruct((), jnp.int32), sched, sched, sched,
+        jax.ShapeDtypeStruct((b,), jnp.int32),
+    )
+    calls = _pallas_calls(jaxpr.jaxpr)
+    assert len(calls) == 1
+    gm = calls[0].params["grid_mapping"]
+    assert tuple(gm.grid) == (b, w)
+    # block sizes per dim; a squeezed (layer) dim has none
+    blocks = [
+        tuple(getattr(dim, "block_size", None) for dim in bm.block_shape)
+        for bm in gm.block_mappings
+    ]
+    assert blocks.count((None, 1, hk, page, d)) == 2  # K and V
+
+
+def _pallas_calls(jaxpr):
+    """Every ``pallas_call`` equation in ``jaxpr`` and its sub-jaxprs."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn)
+        for param in eqn.params.values():
+            inner = getattr(param, "jaxpr", param)
+            if hasattr(inner, "eqns"):
+                found += _pallas_calls(inner)
+    return found
 
 
 @pytest.mark.parametrize("impl", [None, "interpret"])

@@ -23,7 +23,7 @@ from jax.sharding import SingleDeviceSharding
 # qwen3-1.7b: 8 kv heads, 2 query heads per kv head, head_dim 128, and
 # 128-token pages (attn_block); d_model 2048 with 128-blocks for BSR
 HK, G, D, PAGE = 8, 2, 128, 128
-SLOTS, PAGES_PER_SLOT, D_MODEL, BLOCK = 8, 5, 2048, 128
+D_MODEL, BLOCK = 2048, 128
 LAYERS = 4  # a stacked pool's depth (the model has 28)
 
 
@@ -60,22 +60,33 @@ def _compile(fn, *args):
     return compiled
 
 
-def test_paged_decode_kernel_compiles_for_v5e(one_chip):
+@pytest.mark.parametrize(
+    "slots, w",
+    [
+        pytest.param(32, 8, id="pixelfly-chat"),
+        pytest.param(32, 40, id="dense-chat"),
+        pytest.param(16, 9, id="pixelfly-longctx"),
+    ],
+)
+def test_paged_decode_kernel_compiles_for_v5e(one_chip, slots, w):
+    """The decode shapes the cells run: ``w`` schedule pages a slot (every
+    page of 5120 tokens for the dense twin, the sparse schedule of 40 and
+    88 pages for pixelfly); each grid step a (HK, PAGE, D) K/V slab."""
     from repro.kernels.paged_attention import paged_decode_attention_pallas
 
-    n_pages = SLOTS * PAGES_PER_SLOT + 1
+    n_pages = slots * w + 1
     pool = _spec(one_chip, (LAYERS, n_pages, HK, PAGE, D), jnp.bfloat16)
-    sched = _spec(one_chip, (SLOTS, PAGES_PER_SLOT), jnp.int32)
+    sched = _spec(one_chip, (slots, w), jnp.int32)
     _compile(
         lambda *a: paged_decode_attention_pallas(*a, sm_scale=D**-0.5),
-        _spec(one_chip, (SLOTS, HK, G, D), jnp.bfloat16),
+        _spec(one_chip, (slots, HK, G, D), jnp.bfloat16),
         pool,
         pool,
         _spec(one_chip, (), jnp.int32),
         sched,
         sched,
         sched,
-        _spec(one_chip, (SLOTS,), jnp.int32),
+        _spec(one_chip, (slots,), jnp.int32),
     )
 
 
